@@ -35,10 +35,6 @@ CLI:  PYTHONPATH=src python -m benchmarks.fs_reshard [--quick]
 from __future__ import annotations
 
 import os
-
-# 8 fake host devices for the elastic phase — must land before jax loads
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import time
 from typing import Dict
 
@@ -75,13 +71,19 @@ def _host_tree(scale: int) -> Dict[str, np.ndarray]:
     }
 
 
+def _host_mesh(data: int, model: int):
+    """A mesh over the host's CPU devices, whatever accelerator is there."""
+    cpus = jax.devices("cpu")
+    if len(cpus) < 8:
+        raise RuntimeError("elastic phases need 8 host CPU devices "
+                           "(XLA_FLAGS was set too late)")
+    return make_elastic_mesh(data, model, devices=cpus)
+
+
 def run_elastic(scale: int = 4) -> Dict:
     """Save on (2,2), restore onto same/halved/doubled — asserted."""
-    if len(jax.devices()) < 8:
-        raise RuntimeError("elastic phase needs 8 host devices "
-                           "(XLA_FLAGS was set too late)")
     host = _host_tree(scale)
-    mesh_a = make_elastic_mesh(2, 2)
+    mesh_a = _host_mesh(2, 2)
     sh_a = {k: NamedSharding(mesh_a, SPECS[k]) for k in host}
     tree = {k: jax.device_put(jnp.asarray(v), sh_a[k])
             for k, v in host.items()}
@@ -103,7 +105,7 @@ def run_elastic(scale: int = 4) -> Dict:
            "leaf_bytes_total": total_bytes, "shard_files": n_shard_files,
            "save_s": save_s, "restores": {}}
     for name, (d, m) in topos.items():
-        mesh_b = make_elastic_mesh(d, m)
+        mesh_b = _host_mesh(d, m)
         sh_b = {k: NamedSharding(mesh_b, SPECS[k]) for k in host}
         stats: Dict = {}
         t0 = time.perf_counter()
@@ -146,10 +148,6 @@ def run_overlap(scale: int = 32, depth: int = 2, reps: int = 4,
     crossing, i.e. the regime the restore pipeline exists for."""
     import zlib
 
-    if len(jax.devices()) < 8:
-        raise RuntimeError("overlap phase needs 8 host devices "
-                           "(XLA_FLAGS was set too late)")
-
     def cks(raw):  # the userspace binding's checksum (services daemon-side)
         return zlib.crc32(bytes(raw)) & 0xFFFFFFFF
 
@@ -169,7 +167,7 @@ def _run_overlap_cells(mf, cks, host, like, depth, reps,
     out = {"bench": "fs_reshard", "phase": "overlap", "depth": depth,
            "leaf_bytes_total": sum(v.nbytes for v in host.values()),
            "cells": {}}
-    sh_a = {k: NamedSharding(make_elastic_mesh(2, 2), SPECS[k])
+    sh_a = {k: NamedSharding(_host_mesh(2, 2), SPECS[k])
             for k in host}
     tree = {k: jax.device_put(jnp.asarray(v), sh_a[k])
             for k, v in host.items()}
@@ -177,7 +175,7 @@ def _run_overlap_cells(mf, cks, host, like, depth, reps,
               shardings=sh_a)
     serial_total = piped_total = 0.0
     for name, (d, m) in (("halved", (1, 2)), ("doubled", (4, 2))):
-        mesh_b = make_elastic_mesh(d, m)
+        mesh_b = _host_mesh(d, m)
         sh_b = {k: NamedSharding(mesh_b, SPECS[k]) for k in host}
         # untimed warm-up: first restore onto a fresh target mesh pays
         # one-off device_put/layout costs that belong to neither engine
@@ -331,6 +329,10 @@ def main() -> None:
                     help="skip the 8-device elastic phase (jax already "
                          "initialized with fewer devices)")
     args = ap.parse_args()
+    # 8 host CPU devices for the elastic phases: set before jax starts
+    # its backends, which it does at the first device query
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
     scale = 2 if args.quick else 4
     n_tenants = 4 if args.quick else args.tenants
 

@@ -19,12 +19,14 @@ import time
 from repro.core.interface import SubmissionEntry
 from repro.core.registry import BentoQueue
 from repro.fs.mounts import make_mount
+from repro.launch.compile_cache import enable_compile_cache
 
 N = 2048
 SIZE = 4096
 
 
 def main() -> None:
+    enable_compile_cache()
     mf = make_mount("bento", n_blocks=16384)
     v, m, ks = mf.view, mf.mount, mf.services
 

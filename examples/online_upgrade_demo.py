@@ -17,6 +17,7 @@ from repro.configs import registry
 from repro.core.upgrade import transfer_state, unwrap_layer, wrap_layer
 from repro.fs.mounts import make_mount
 from repro.fs.prov import ProvFilesystem
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.trainer import Trainer
 
 
@@ -86,6 +87,7 @@ def trainer_module_upgrade():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     try:
         prov_hot_swap_under_load()
         trainer_module_upgrade()

@@ -15,11 +15,13 @@ from repro.core.upgrade import upgrade
 from repro.distributed.sharding import ShardingCtx
 from repro.fs.ext4like import Ext4LikeFileSystem
 from repro.fs.mounts import make_mount
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.step import make_decode_step, make_prefill_step
 from repro.train.trainer import Trainer
 
 
 def main():
+    enable_compile_cache()
     bundle = registry.get("smollm-135m")
     cfg = bundle.smoke  # reduced config: runs on CPU in seconds
     run = bundle.run.replace(microbatch_per_data_shard=0, learning_rate=1e-3)

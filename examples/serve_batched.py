@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs import registry
 from repro.distributed.sharding import ShardingCtx
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm, params as P
 from repro.serve.step import make_decode_step, make_prefill_step
 
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     bundle = registry.get(args.arch)
     cfg, run = bundle.smoke, bundle.run

@@ -11,8 +11,9 @@ import time
 
 from repro.configs import registry
 from repro.data.pipeline import FsShardReader, SyntheticLM, write_shards
-from repro.fs.mounts import make_mount
-from repro.train.trainer import Trainer, WorkerFailure
+from repro.fs.mounts import blocks_for, make_mount
+from repro.launch.compile_cache import enable_compile_cache
+from repro.train.trainer import Trainer, WorkerFailure, state_nbytes
 
 
 class FsDataset:
@@ -37,12 +38,17 @@ def main():
     ap.add_argument("--fail-at", type=int, default=-1,
                     help="inject a node failure at this step")
     args = ap.parse_args()
+    enable_compile_cache()
 
     bundle = registry.get("smollm-135m")
     cfg = bundle.model if args.full else bundle.smoke
     run = bundle.run.replace(microbatch_per_data_shard=0, learning_rate=6e-4)
 
-    mf = make_mount("bento", n_blocks=65536)
+    ckpt_every = max(args.steps // 10, 1)
+    # every save is kept: size the device for all of them
+    saves = args.steps // ckpt_every
+    mf = make_mount("bento",
+                    n_blocks=blocks_for(saves * state_nbytes(cfg, run)))
     data = FsDataset(mf.view, cfg, args.batch, args.seq)
 
     armed = {"on": args.fail_at >= 0}
@@ -53,7 +59,7 @@ def main():
             raise WorkerFailure(f"injected node loss at step {step}")
 
     t = Trainer(cfg, run, global_batch=args.batch, seq_len=args.seq,
-                ckpt_view=mf.view, ckpt_every=max(args.steps // 10, 1),
+                ckpt_view=mf.view, ckpt_every=ckpt_every,
                 failure_hook=failure_hook if args.fail_at >= 0 else None,
                 data=data)
     t0 = time.time()

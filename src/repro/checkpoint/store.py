@@ -167,10 +167,16 @@ def _flatten(tree):
     return leaves, treedef
 
 
-def _flatten_shardings(tree) -> List:
-    """Flatten a per-leaf sharding/grid tree. None entries mean "this leaf
-    is unsharded" and must stay leaves, not collapse as empty subtrees."""
-    return jax.tree.flatten(tree, is_leaf=lambda v: v is None)[0]
+def _flatten_shardings(treedef, tree) -> List:
+    """Flatten a per-leaf sharding/grid tree up to the data tree's
+    structure: None where the data has a leaf means "this leaf is
+    unsharded"; None where the data has None (an absent subtree, such as
+    an optimizer's unused master copy) stays absent."""
+    try:
+        return treedef.flatten_up_to(tree)
+    except (ValueError, TypeError) as e:
+        raise ValueError(f"shardings tree does not match the model's "
+                         f"structure — incompatible trees: {e}") from e
 
 
 def _np_dtype(dtype_s: str) -> np.dtype:
@@ -261,11 +267,7 @@ def save(view: PosixView, root: str, tree, *, step: int,
     leaves, treedef = _flatten(tree)
     grids = None
     if shardings is not None:
-        grids = _flatten_shardings(shardings)
-        if len(grids) != len(leaves):
-            raise ValueError(
-                f"shardings tree has {len(grids)} leaves, model has "
-                f"{len(leaves)} — incompatible trees")
+        grids = _flatten_shardings(treedef, shardings)
     manifest_path = f"{root}/{MANIFEST}"
     # Re-saves bump a GENERATION tag baked into the shard names, so the new
     # files never overwrite the ones the LIVE manifest references — the
@@ -1269,8 +1271,9 @@ def _leaf_tasks(view: PosixView, rec: Dict, target, checksum,
 def _build_tasks(view: PosixView, recs, shardings, checksum,
                  checksum_batch, depth: int, out, note) -> List[_Task]:
     """Compile the whole restore into one ordered task list: single-shard
-    leaves batch v1-style (one crossing per ~``_BATCH_FILES`` whole
-    files, one hash launch per fetched chunk); multi-shard leaves expand
+    leaves batch v1-style (one crossing per ``_BATCH_FILES`` whole files
+    or ~``_BATCH_BYTES``, as the save batches its writes; one hash launch
+    per fetched chunk); multi-shard leaves expand
     through the reshard plan compiler. Every multi-shard leaf gets its
     OWN admission window (simple batches share one): an oversized fetch
     is exclusive only within its leaf, so leaf N+1 prefetches while
@@ -1331,7 +1334,8 @@ def _build_tasks(view: PosixView, recs, shardings, checksum,
             batch["est"] += index_volume(
                 tuple((0, int(d)) for d in rec["shape"])) \
                 * _np_dtype(rec["dtype"]).itemsize + 512
-            if len(batch["idx"]) >= _BATCH_FILES:
+            if len(batch["idx"]) >= _BATCH_FILES \
+                    or batch["est"] >= _BATCH_BYTES:
                 flush_simple()
         else:
             peak, info = _Peak(), {}
@@ -1372,11 +1376,7 @@ def load(view: PosixView, root: str, like_tree, *, checksum=None,
     recs = _validate_manifest(manifest, leaves_like, treedef)
     shardings: List[Any] = [None] * len(leaves_like)
     if sharding_tree is not None:
-        shardings = _flatten_shardings(sharding_tree)
-        if len(shardings) != len(leaves_like):
-            raise ValueError(
-                f"sharding tree has {len(shardings)} leaves, model has "
-                f"{len(leaves_like)} — incompatible trees")
+        shardings = _flatten_shardings(treedef, sharding_tree)
     out: List[Any] = [None] * len(recs)
     leaf_stats: List[Dict] = []
 

@@ -4,15 +4,18 @@ Extensions never touch raw devices or kernel structures; they call these
 methods with capability proof. Two bindings expose the SAME API (paper §4.9
 — same code in kernel and userspace):
 
-* ``kernel_binding``   — host-memory device, Pallas-crc32c checksums
-                         (TPU-path checksum; interpret-mode on CPU),
+* ``kernel_binding``   — host-memory device, Pallas blockhash checksums
+                         on a TPU, zlib crc32 elsewhere,
 * ``userspace_binding`` — file-backed device, zlib crc32.
+
+``KernelServices.checksum_impl`` names the hash a binding bound.
 
 Swap the binding, not the file system.
 """
 
 from __future__ import annotations
 
+import functools
 import threading
 import time as _time
 import zlib
@@ -41,9 +44,10 @@ class KernelServices:
     def __init__(self, dev: BlockDevice, *, checksum: Callable[[bytes], int],
                  checksum_batch: Optional[Callable] = None,
                  writeback: str = "delayed", cache_capacity: int = 4096,
-                 binding: str = "kernel"):
+                 binding: str = "kernel", checksum_impl: str = "crc32"):
         self._dev = dev
         self.binding = binding
+        self.checksum_impl = checksum_impl
         self._cache = BufferCache(dev, capacity=cache_capacity,
                                   writeback=writeback)
         self._sb_state = _SbState(dev, self._cache)
@@ -150,32 +154,38 @@ def _crc32_zlib(data: bytes) -> int:
     return zlib.crc32(data) & 0xFFFFFFFF
 
 
-def _blockhash_pallas(data: bytes) -> int:
-    from repro.kernels.blockhash import ops as bh_ops
-
-    return bh_ops.checksum(data)
-
-
 def kernel_binding(dev: BlockDevice, **kw) -> KernelServices:
-    """Kernel-mode services: Pallas blockhash checksums on TPU (interpret
-    mode is a correctness harness, not a perf path — on CPU the host crc is
-    used unless REPRO_FORCE_PALLAS_CHECKSUM=1, which tests set)."""
+    """Kernel-mode services. On a TPU every checksum goes through the
+    Pallas blockhash kernel, probed against its host reference at bind
+    time; a probe that fails raises, it never falls back. Elsewhere the
+    host crc32 is bound, unless REPRO_FORCE_PALLAS_CHECKSUM=1 asks for the
+    kernel in interpret mode (slow, but real launches)."""
     import os
 
     import jax
 
-    use_pallas = (jax.default_backend() == "tpu"
-                  or os.environ.get("REPRO_FORCE_PALLAS_CHECKSUM") == "1")
-    cks, cks_b = _crc32_zlib, None
-    if use_pallas:
-        try:
-            from repro.kernels.blockhash import ops as bh_ops
-            bh_ops.checksum(b"probe")  # probe at bind time, not commit time
-            cks, cks_b = _blockhash_pallas, bh_ops.checksum_batch
-        except Exception:  # kernels unavailable/broken — fall back
-            pass
-    return KernelServices(dev, checksum=cks, checksum_batch=cks_b,
-                          binding="kernel", **kw)
+    on_tpu = jax.default_backend() == "tpu"
+    if not (on_tpu or os.environ.get("REPRO_FORCE_PALLAS_CHECKSUM") == "1"):
+        return KernelServices(dev, checksum=_crc32_zlib, binding="kernel",
+                              **kw)
+    from repro.kernels.blockhash import ops as bh_ops
+    from repro.kernels.blockhash.ref import blockhash_np
+
+    interpret = not on_tpu
+    probe = bytes(range(256)) * 17  # spans two rows
+    got = [bh_ops.checksum(probe, interpret=interpret)] + \
+        bh_ops.checksum_batch([probe[:4096]], interpret=interpret)
+    want = [blockhash_np(probe), blockhash_np(probe[:4096])]
+    if got != want:
+        raise RuntimeError(
+            f"blockhash kernel probe returned {got}, reference {want}")
+    return KernelServices(
+        dev, checksum=functools.partial(bh_ops.checksum, interpret=interpret),
+        checksum_batch=functools.partial(bh_ops.checksum_batch,
+                                         interpret=interpret),
+        binding="kernel",
+        checksum_impl="blockhash-interpret" if interpret else
+        "blockhash-pallas", **kw)
 
 
 def userspace_binding(dev: BlockDevice, **kw) -> KernelServices:

@@ -23,7 +23,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 
@@ -80,11 +79,11 @@ def pipeline_apply(stage_fn: Callable, params_stages, x_microbatches, mesh,
         return jax.lax.psum(contrib, axis)
 
     in_param_specs = jax.tree.map(lambda _: PS(axis), params_stages)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(in_param_specs, PS()),
         out_specs=PS(),
-        check_rep=False,
+        check_vma=False,
     )(params_stages, x_microbatches)
 
 

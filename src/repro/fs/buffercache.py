@@ -89,6 +89,9 @@ class BufferCache:
         self._blocks: "collections.OrderedDict[int, bytearray]" = collections.OrderedDict()
         self._dirty: Dict[int, bytearray] = {}
         self._refs: Dict[int, int] = collections.defaultdict(int)
+        # set when an eviction pass found every cached block pinned or
+        # dirty; cleared when one may have become evictable
+        self._all_pinned = False
         self.hits = 0
         self.misses = 0
 
@@ -174,14 +177,25 @@ class BufferCache:
             return BufferHead(blockno, buf, self)
 
     def _insert(self, blockno: int, buf: bytearray) -> None:
+        """Cache ``buf`` and evict the oldest unpinned, clean blocks down to
+        capacity. The block being inserted is never a victim: its caller
+        pins it next. When a whole pass finds nothing to evict the cache
+        grows past capacity, and later inserts skip the pass until a block
+        is released or cleaned — a bulk read larger than the cache then
+        costs one pass, not one per block."""
         self._blocks[blockno] = buf
+        if self._all_pinned:
+            return
+        rotated = 0
         while len(self._blocks) > self.capacity:
-            old, obuf = next(iter(self._blocks.items()))
-            if self._refs.get(old, 0) > 0 or old in self._dirty:
+            old = next(iter(self._blocks))
+            if old == blockno or self._refs.get(old, 0) > 0 \
+                    or old in self._dirty:
+                if rotated >= len(self._blocks):
+                    self._all_pinned = True  # grow past capacity
+                    return
                 self._blocks.move_to_end(old)  # pinned/dirty: skip
-                if all(self._refs.get(b, 0) > 0 or b in self._dirty
-                       for b in self._blocks):
-                    break  # everything pinned — grow past capacity
+                rotated += 1
                 continue
             self._blocks.popitem(last=False)
             self._refs.pop(old, None)
@@ -208,11 +222,12 @@ class BufferCache:
         else:
             # drop zero entries so the refs dict IS the held-set
             self._refs.pop(bh.blockno, None)
-        if bh.dirty:
-            if self.writeback == "through":
+        if bh.dirty and self.writeback == "delayed":
+            self._dirty[bh.blockno] = bh._buf
+        else:
+            if bh.dirty:
                 self.dev.write_block(bh.blockno, bytes(bh._buf))
-            else:
-                self._dirty[bh.blockno] = bh._buf
+            self._all_pinned = False
 
     def brelse_many(self, heads: List[BufferHead]) -> None:
         """Release many heads under ONE lock acquisition — the unpin
@@ -229,6 +244,7 @@ class BufferCache:
             self.dev.write_block(bh.blockno, bytes(bh.data()))
             self._dirty.pop(bh.blockno, None)
             bh.dirty = False
+            self._all_pinned = False
 
     def flush(self, blocknos: Optional[List[int]] = None) -> int:
         """Batched writeback (`writepages`): contiguous runs written in order."""
@@ -239,6 +255,7 @@ class BufferCache:
                 self.dev.write_block(b, bytes(self._dirty[b]))
             for b in targets:
                 del self._dirty[b]
+            self._all_pinned = False
             self.dev.sync()
             return len(targets)
 

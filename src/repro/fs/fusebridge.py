@@ -373,6 +373,9 @@ class FuseMount:
         src_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
+        # the chip belongs to the mounting process: the daemon stays on
+        # the host CPU and never tries to open it
+        env["JAX_PLATFORMS"] = "cpu"
         self._proc = subprocess.Popen(
             [sys.executable, "-m", "repro.fs.fusebridge", sock_path,
              backing_path, str(n_blocks), fs_kind,

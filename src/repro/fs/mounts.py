@@ -21,6 +21,7 @@ from repro.core.services import kernel_binding, userspace_binding
 from repro.fs.blockdev import LazyBlockDevice, MemBlockDevice
 from repro.fs.ext4like import Ext4LikeFileSystem
 from repro.fs.fusebridge import FuseMount
+from repro.fs.layout import BSIZE
 from repro.fs.overlay import OverlayFilesystem, OverlayOptions
 from repro.fs.posix import PosixView
 from repro.fs.xv6 import Xv6FileSystem, Xv6Options, mkfs
@@ -65,6 +66,13 @@ class MountedFs:
 
     def close(self) -> None:
         self.mount.unmount()
+
+
+def blocks_for(nbytes: int) -> int:
+    """Device size, in blocks, for a mount that will hold ``nbytes`` of
+    file data: a quarter more covers indirect blocks, metadata and slack,
+    and no device is smaller than ``make_mount``'s default."""
+    return max(16384, -(-(nbytes * 5 // 4) // BSIZE))
 
 
 def make_mount(kind: str, n_blocks: int = 16384, *,
@@ -136,6 +144,16 @@ def make_mount(kind: str, n_blocks: int = 16384, *,
         m = bento_mount("ext4like", ks, module=fs)
         return MountedFs(kind, m, PosixView(m), ks, dev)
     raise KeyError(kind)
+
+
+def remount(dev: MemBlockDevice) -> MountedFs:
+    """Mount a ``bento`` device that already holds a file system, cold: a
+    fresh binding, cache and module, no mkfs, and the module's init
+    replays the journal (``Journal.recover``)."""
+    ks = kernel_binding(dev)
+    fs = Xv6FileSystem(Xv6Options(group_commit=True, batched_install=True))
+    m = bento_mount("xv6", ks, module=fs)
+    return MountedFs("bento", m, PosixView(m), ks, dev)
 
 
 # --- CoW overlay provisioning (repro.fs.overlay) ----------------------------------

@@ -1,4 +1,12 @@
-"""Host-facing checksum API used by the kernel-services binding."""
+"""Host-facing checksum API used by the kernel-services binding.
+
+A buffer is hashed as rows of 4 KiB: the row kernel hashes each row, and
+the row hashes combine as h = sum_r h_r * P^(1024 * rows after r). Zero
+words in front of a buffer leave its hash unchanged, so every buffer is
+right-aligned in its rows and the row count is padded at the front to a
+bucket: a handful of compiled shapes serve every length. Interpret mode
+runs only where the caller asks for it.
+"""
 
 from __future__ import annotations
 
@@ -11,40 +19,84 @@ import numpy as np
 from repro.kernels.blockhash import kernel as K
 from repro.kernels.blockhash import ref
 
-
-@functools.lru_cache(maxsize=8)
-def _pows(n: int) -> np.ndarray:
-    return ref.powers(n)
+ROW_BYTES = 4 * K.WORDS
+STEP_ROWS = 256  # rows per grid step of a long buffer: 1 MiB of VMEM
 
 
-@functools.lru_cache(maxsize=8)
-def _jitted(wpb: int, interpret: bool):
-    pows = jnp.asarray(_pows(wpb))
-
-    @jax.jit
-    def f(words):
-        return K.blockhash_batch(words, pows, interpret=interpret)
-
-    return f
-
-
-def checksum(data: bytes, *, interpret=None) -> int:
-    """Checksum one block (journal commit-record entries)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    pad = (-len(data)) % 4
-    arr = np.frombuffer(data + b"\0" * pad, dtype=np.uint32)[None, :]
-    out = _jitted(arr.shape[1], interpret)(jnp.asarray(arr))
-    return int(out[0])
+def bucket(n: int):
+    """Rows a batch of ``n`` rows is padded to, and the rows per grid
+    step: a power of two from 8 up to STEP_ROWS, then multiples of a step
+    that keeps the padding under an eighth."""
+    if n <= STEP_ROWS:
+        rows = max(8, 1 << (n - 1).bit_length())
+        return rows, rows
+    step = max(STEP_ROWS, 1 << (n.bit_length() - 4))
+    return -(-n // step) * step, STEP_ROWS
 
 
-def checksum_batch(blocks, *, interpret=None) -> list:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    arrs = []
-    for data in blocks:
-        pad = (-len(data)) % 4
-        arrs.append(np.frombuffer(data + b"\0" * pad, dtype=np.uint32))
-    words = np.stack(arrs)
-    out = _jitted(words.shape[1], interpret)(jnp.asarray(words))
-    return [int(x) for x in out]
+@functools.lru_cache(maxsize=None)
+def _pows() -> jax.Array:
+    return jnp.asarray(ref.powers(K.WORDS)[None, :])
+
+
+@functools.lru_cache(maxsize=32)
+def _row_mults(rows: int) -> jax.Array:
+    return jnp.asarray(ref.powers(rows, pow(int(ref.PRIME), K.WORDS, 1 << 32)))
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def hash_rows(words, pows, *, block_rows: int, interpret: bool = False):
+    """(rows, 1024) uint32 -> (rows,) uint32 row hashes."""
+    return K.blockhash_batch(words, pows, block_rows=block_rows,
+                             interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def hash_buffer(words, pows, mults, *, block_rows: int,
+                interpret: bool = False):
+    """(rows, 1024) uint32 -> the uint32 hash of all rows in order."""
+    h = K.blockhash_batch(words, pows, block_rows=block_rows,
+                          interpret=interpret)
+    as_i32 = functools.partial(jax.lax.bitcast_convert_type,
+                               new_dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(
+        jnp.sum(as_i32(h) * as_i32(mults), dtype=jnp.int32), jnp.uint32)
+
+
+def _place(row: np.ndarray, data) -> None:
+    """Right-align ``data`` in ``row``, zero-filled to a word boundary."""
+    start = row.size - 4 * (-(-len(data) // 4))
+    row[start:start + len(data)] = np.frombuffer(data, np.uint8)
+
+
+def checksum(data, *, interpret: bool = False) -> int:
+    """Hash one buffer of any length (a block, a whole shard file)."""
+    rows, block_rows = bucket(max(1, -(-len(data) // ROW_BYTES)))
+    buf = np.zeros(rows * ROW_BYTES, np.uint8)
+    _place(buf, data)
+    words = buf.view(np.uint32).reshape(rows, K.WORDS)
+    out = hash_buffer(words, _pows(), _row_mults(rows),
+                      block_rows=block_rows, interpret=interpret)
+    return int(out)
+
+
+def checksum_batch(blocks, *, interpret: bool = False) -> list:
+    """Hash many buffers in one launch when each fits a row (the journal
+    commit's blocks); longer buffers are hashed one by one."""
+    blocks = list(blocks)
+    if not blocks:
+        return []
+    if any(len(b) > ROW_BYTES for b in blocks):
+        return [checksum(b, interpret=interpret) for b in blocks]
+    n = len(blocks)
+    rows, block_rows = bucket(n)
+    buf = np.zeros((rows, ROW_BYTES), np.uint8)
+    if all(len(b) == ROW_BYTES for b in blocks):
+        buf[:n] = np.frombuffer(b"".join(blocks), np.uint8).reshape(
+            n, ROW_BYTES)
+    else:
+        for row, b in zip(buf, blocks):
+            _place(row, b)
+    out = hash_rows(buf.view(np.uint32), _pows(), block_rows=block_rows,
+                    interpret=interpret)
+    return np.asarray(out)[:n].tolist()

@@ -1,4 +1,4 @@
-"""Pure-jnp oracle for the journal block-checksum kernel.
+"""Host reference for the block-checksum kernel.
 
 Polynomial hash over u32 words: h = sum_i word_i * P^(n-1-i)  (mod 2^32),
 P = 0x01000193 (FNV prime). Chosen over CRC32C because CRC's bit-serial
@@ -9,26 +9,18 @@ detection strength is equivalent for journal-commit purposes.
 
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
 PRIME = np.uint32(0x01000193)
 
 
-def powers(n: int) -> np.ndarray:
-    """[P^(n-1), ..., P^1, P^0] mod 2^32."""
-    out = np.empty(n, dtype=np.uint32)
-    acc = np.uint32(1)
-    for i in range(n - 1, -1, -1):
-        out[i] = acc
-        acc = np.uint32((int(acc) * int(PRIME)) & 0xFFFFFFFF)
-    return out
-
-
-def blockhash(words: jnp.ndarray, pows: jnp.ndarray) -> jnp.ndarray:
-    """words, pows: (n,) uint32 -> scalar uint32."""
-    return jnp.sum(words.astype(jnp.uint32) * pows.astype(jnp.uint32),
-                   dtype=jnp.uint32)
+def powers(n: int, base: int = PRIME) -> np.ndarray:
+    """[base^(n-1), ..., base^1, base^0] mod 2^32 (u32 products wrap)."""
+    asc = np.ones(1, dtype=np.uint32)
+    while asc.size < n:  # doubling: [b^0..b^(k-1)] -> [b^0..b^(2k-1)]
+        asc = np.concatenate(
+            [asc, asc * np.uint32(pow(int(base), asc.size, 1 << 32))])
+    return asc[:n][::-1].copy()
 
 
 def blockhash_np(data: bytes) -> int:

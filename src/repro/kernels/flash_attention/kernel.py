@@ -2,8 +2,10 @@
 
 Tiling: grid (batch, q_head, Sq/block_q, Skv/block_kv), kv innermost with
 "arbitrary" semantics so the (m, l, acc) VMEM scratch carries across kv
-steps — the online-softmax recurrence. Block shapes are MXU-aligned
-(block_q x D and block_kv x D tiles; D rides the 128-lane dim). Fully
+steps — the online-softmax recurrence. The wrapper moves heads in front
+of the sequence, (B, H, S, D), so each block's last two dims are
+(block, D): a whole-D lane dim and a sequence sublane dim, the tiling
+Mosaic requires. Batch and head are squeezed out of the block. Fully
 masked causal/SWA blocks are skipped with ``pl.when`` (no MXU work issued),
 so kernel FLOPs match the causal-optimal count — replacing the XLA
 chunked-softmax path's ~2x causal waste on TPU.
@@ -22,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -51,9 +51,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bkv, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)  # (bq, D)
+        k = k_ref[...].astype(jnp.float32)  # (bkv, D)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * scale
@@ -67,19 +67,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         if window > 0:
             mask &= kpos > (qpos - window)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_scr[...]  # (bq, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+        p = jnp.exp(s - m_cur)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_scr[...] = m_cur
 
     @pl.when(ik == n_kv - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=0, softcap=0.0,
@@ -99,26 +99,26 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, softcap=0.0,
         _fwd_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_kv=block_kv, n_kv=n_kv)
 
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, None, block_q, D),
+                          lambda b, h, iq, ik: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec((None, None, block_kv, D),
+                           lambda b, h, iq, ik, G=G: (b, h // G, ik, 0))
+    heads_first = functools.partial(jnp.swapaxes, axis1=1, axis2=2)
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, D),
-                         lambda b, h, iq, ik, G=G: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_kv, 1, D),
-                         lambda b, h, iq, ik, G=G: (b, ik, h // G, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+        name="flash_attention_fwd",
+    )(heads_first(q), heads_first(k), heads_first(v))
+    return heads_first(out)

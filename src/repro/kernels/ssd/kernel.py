@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, s0_ref,
             y_ref, sout_ref, s_scr, *, chunk: int, n_chunks: int):
@@ -95,7 +93,7 @@ def ssd_chunked(x, dt, B, C, A_log, D, state, *, chunk=128, interpret=False):
             jax.ShapeDtypeStruct((b, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, B, C, A_log, D, state)

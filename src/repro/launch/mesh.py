@@ -11,11 +11,10 @@ import jax
 
 
 def _make_mesh(shape, axes, **kw):
-    # jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist
-    # on newer jax lines; Auto is already the default everywhere it does
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+    # make_mesh defaults to Explicit axes; the sharding rules constrain
+    # with_sharding_constraint-style, which needs Auto
+    return jax.make_mesh(shape, axes,
+                         (jax.sharding.AxisType.Auto,) * len(axes), **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs import registry
 from repro.configs.base import ShapeSpec
 from repro.distributed.sharding import ShardingCtx
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm, params as P
 from repro.serve.step import make_decode_step, make_prefill_step
 
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     bundle = registry.get(args.arch)
     cfg = bundle.smoke if args.smoke else bundle.model

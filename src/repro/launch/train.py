@@ -17,9 +17,10 @@ import time
 import jax
 
 from repro.configs import registry
-from repro.fs.mounts import make_mount
+from repro.fs.mounts import blocks_for, make_mount
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.train.trainer import Trainer
+from repro.train.trainer import Trainer, state_nbytes
 
 
 def main() -> None:
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     bundle = registry.get(args.arch)
     cfg = bundle.smoke if args.smoke else bundle.model
@@ -44,7 +46,10 @@ def main() -> None:
     mf = None
     ckpt_view = None
     if args.ckpt_every:
-        mf = make_mount("bento", n_blocks=65536)
+        # every save is kept: size the device for all of them
+        saves = max(1, args.steps // args.ckpt_every)
+        mf = make_mount("bento",
+                        n_blocks=blocks_for(saves * state_nbytes(cfg, run)))
         ckpt_view = mf.view
 
     t = Trainer(cfg, run, global_batch=args.batch, seq_len=args.seq,

@@ -179,8 +179,18 @@ def attention_auto(q, k, v, *, causal=True, window=0, softcap=0.0, q_chunk=1024,
         bq, bk = _flash_blocks(q.shape[1]), _flash_blocks(k.shape[1])
         if bq and bk:
             from repro.kernels.flash_attention import ops as fa
-            return fa.flash_attention(q, k, v, causal, window, softcap,
-                                      None if on_tpu else True)
+
+            def kernel(q, k, v):
+                return fa.flash_attention(q, k, v, causal, window, softcap,
+                                          not on_tpu)
+
+            if ctx is None or ctx.mesh is None:
+                return kernel(q, k, v)
+            # a Mosaic kernel cannot be partitioned by XLA: run it per
+            # batch shard, every head of that shard on its own device
+            spec = ctx.spec(("batch",), q.shape[:1])
+            return jax.shard_map(kernel, mesh=ctx.mesh, in_specs=(spec,) * 3,
+                                 out_specs=spec, check_vma=False)(q, k, v)
     if q.shape[1] >= 2048 and q.shape[1] % q_chunk == 0:
         return attention_chunked(q, k, v, causal=causal, window=window,
                                  softcap=softcap, q_chunk=q_chunk, ctx=ctx)
@@ -265,8 +275,6 @@ def flash_decode(q, cache_k, cache_v, pos, mesh, *, axis="model", softcap=0.0,
     batch rows matching its cache shard, attends locally, and a tiny
     all_gather re-replicates the output.
     """
-    from jax.experimental.shard_map import shard_map
-
     B, _, Hq, D = q.shape
     S = cache_k.shape[1]
     n_shards = mesh.devices.shape[list(mesh.axis_names).index(axis)]
@@ -318,11 +326,11 @@ def flash_decode(q, cache_k, cache_v, pos, mesh, *, axis="model", softcap=0.0,
 
     spec_q = PS(None) if q_replicated or not batch_axes else PS(batch_axes)
     spec_kv = PS(batch_axes if batch_axes else None, axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(spec_q, spec_kv, spec_kv, PS()),
         out_specs=spec_q,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, cache_k, cache_v, jnp.broadcast_to(pos, (1,)))
 

@@ -25,7 +25,6 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from repro.configs.base import ModelConfig
@@ -118,14 +117,14 @@ def moe_a2a_apply(cfg: ModelConfig, ctx: ShardingCtx, w, x: jax.Array):
         return y.reshape(B_loc, S, d), aux
 
     bspec = PS(batch_axes if batch_axes else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(PS(batch_axes if batch_axes else None, None, None),
                   PS(None, None),
                   PS("model", None, None), PS("model", None, None),
                   PS("model", None, None)),
         out_specs=(PS(batch_axes if batch_axes else None, None, None), PS()),
-        check_rep=False,
+        check_vma=False,
     )
     y, aux = fn(x, w["router"], w["w_gate"], w["w_up"], w["w_down"])
     if cfg.shared_expert:

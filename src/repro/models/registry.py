@@ -18,10 +18,8 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         return total
     # Identify expert weights (w_gate/w_up/w_down with leading E axis).
     expert = 0
-    # jax.tree_util spelling: jax.tree.flatten_with_path only exists on
-    # newer jax lines
-    from jax.tree_util import tree_flatten_with_path
-    flat, _ = tree_flatten_with_path(specs, is_leaf=P.is_spec)
+    import jax
+    flat, _ = jax.tree.flatten_with_path(specs, is_leaf=P.is_spec)
     for path, spec in flat:
         keys = [getattr(p, "key", None) for p in path]
         if "moe" in keys and any(k in ("w_gate", "w_up", "w_down") for k in keys):

@@ -15,6 +15,7 @@ fault-tolerance features:
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Callable, Dict, Optional
 
@@ -29,6 +30,22 @@ from repro.models import lm, params as P
 from repro.optim.adamw import adamw_init_specs
 from repro.train.step import make_train_step
 from repro import checkpoint as ckpt
+
+
+def _init_state(pspecs, ospecs, run: RunConfig, rng):
+    """Fresh parameters and optimizer state from their specs."""
+    return (P.materialize(pspecs, rng, dtype=run.param_dtype),
+            P.materialize(ospecs, rng, dtype="float32"))
+
+
+def state_nbytes(cfg: ModelConfig, run: RunConfig) -> int:
+    """Bytes of a Trainer's parameters plus optimizer state — what one
+    checkpoint stores — from their specs, without materializing them."""
+    pspecs = lm.param_specs(cfg)
+    shapes = jax.eval_shape(
+        functools.partial(_init_state, pspecs, adamw_init_specs(pspecs, run),
+                          run), jax.random.PRNGKey(0))
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
 
 
 class WorkerFailure(Exception):
@@ -83,12 +100,14 @@ class Trainer(BentoModule):
             self._step_fn = jax.jit(fn, donate_argnums=(0, 1))
 
     def _init_state(self) -> None:
-        rng = jax.random.PRNGKey(self.seed)
-        self.params = P.materialize(self.pspecs, rng, dtype=self.run.param_dtype)
-        self.opt_state = P.materialize(self.ospecs, rng, dtype="float32")
-        if self.param_shardings is not None:
-            self.params = jax.device_put(self.params, self.param_shardings)
-            self.opt_state = jax.device_put(self.opt_state, self.opt_shardings)
+        # one compiled program for the whole state, made where it is
+        # sharded: op by op, an accelerator compiles each op of each leaf
+        shardings = (None if self.param_shardings is None
+                     else (self.param_shardings, self.opt_shardings))
+        init = jax.jit(functools.partial(_init_state, self.pspecs,
+                                         self.ospecs, self.run),
+                       out_shardings=shardings)
+        self.params, self.opt_state = init(jax.random.PRNGKey(self.seed))
 
     # --- stepping ------------------------------------------------------------------
     def _fetch(self, step: int) -> Dict[str, np.ndarray]:
@@ -158,11 +177,17 @@ class Trainer(BentoModule):
             return None
         return {"params": self.param_shardings, "opt": self.opt_shardings}
 
+    def _ckpt_services(self):
+        """The mount's kernel services (None on a FUSE mount): their hash
+        checksums every shard on save and verifies it on restore."""
+        return getattr(getattr(self.ckpt_view, "m", None), "services", None)
+
     def save_checkpoint(self) -> None:
         """Shard-per-file v2 save: the live shardings become the stored
         shard grid, so a restart on a different mesh reshards on restore
         instead of gathering full tensors."""
         assert self.ckpt_view is not None
+        ks = self._ckpt_services()
         root = f"{self.ckpt_root}/step_{self.step_idx:08d}"
         extra = None
         if self.mesh is not None:
@@ -172,6 +197,7 @@ class Trainer(BentoModule):
         ckpt.save(self.ckpt_view, root,
                   {"params": self.params, "opt": self.opt_state},
                   step=self.step_idx, shardings=self._ckpt_shardings(),
+                  checksum=ks.checksum if ks is not None else None,
                   extra=extra, pipeline_depth=self.ckpt_pipeline_depth)
 
     def restore_checkpoint(self, step: Optional[int] = None) -> bool:
@@ -183,8 +209,11 @@ class Trainer(BentoModule):
         root = f"{self.ckpt_root}/step_{step:08d}"
         like = {"params": self.params, "opt": self.opt_state}
         self.last_restore_stats = {}
+        ks = self._ckpt_services()
         tree, _mf = ckpt.load(
             self.ckpt_view, root, like,
+            checksum=ks.checksum if ks is not None else None,
+            checksum_batch=ks.checksum_batch if ks is not None else None,
             sharding_tree=self._ckpt_shardings(),
             stats=self.last_restore_stats,
             pipeline_depth=self.ckpt_pipeline_depth)

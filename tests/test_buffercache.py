@@ -8,6 +8,7 @@ interpreter/cache teardown sprayed "Exception ignored in __del__" noise.
 These tests pin the idempotent-release protocol that closed them.
 """
 
+import collections
 import gc
 
 import pytest
@@ -98,3 +99,34 @@ def test_bread_many_failure_strands_no_pins():
         cache.bread_many([0, 1, 2])
     cache.assert_no_leaks()
     assert cache._refs == {}
+
+
+def test_bulk_read_past_capacity_is_one_pass():
+    """A bread_many larger than the cache pins every block it returns, so
+    the cache grows past capacity. That takes one eviction pass, not one
+    pass over every pinned block per inserted block (a 27,648-block shard
+    read was quadratic), and the cache shrinks back once the heads go."""
+    n, cap = 5000, 64
+
+    class CountingDict(collections.OrderedDict):
+        moves = 0
+
+        def move_to_end(self, key, last=True):
+            CountingDict.moves += 1
+            super().move_to_end(key, last)
+
+    dev = MemBlockDevice(n + 1)
+    for b in range(n):
+        dev.write_block(b, bytes([b % 251]) * dev.block_size)
+    cache = BufferCache(dev, capacity=cap)
+    cache._blocks = CountingDict()
+    for b in range(cap):  # a full cache of unpinned blocks to evict first
+        cache.bread(b).brelse()
+    heads = cache.bread_many(list(range(n)))
+    assert [h.data()[0] for h in heads] == [b % 251 for b in range(n)]
+    assert len(cache._blocks) == n
+    assert CountingDict.moves < 3 * n
+    cache.brelse_many(heads)
+    cache.bread(n).brelse()
+    assert len(cache._blocks) == cap
+    cache.assert_no_leaks()

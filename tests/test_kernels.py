@@ -140,34 +140,118 @@ def test_ssd_chunked_equals_stepwise():
 @pytest.mark.parametrize("nbytes", [16, 512, 4096, 4093])
 def test_blockhash(nbytes):
     data = os.urandom(nbytes)
-    assert bh_ops.checksum(data) == bh_ref.blockhash_np(data)
+    assert bh_ops.checksum(data, interpret=True) == bh_ref.blockhash_np(data)
+
+
+@pytest.mark.parametrize("nbytes", [0, 3, 4096, 9 * 4096 + 5])
+def test_blockhash_reference_matches_plain_loop(nbytes):
+    """The vectorized reference (doubling powers, any base) against
+    Horner's rule word by word, so the kernel and its reference cannot
+    share a wrong power table."""
+    data = np.random.default_rng(nbytes).bytes(nbytes)
+    words = np.frombuffer(data + b"\0" * (-nbytes % 4), np.uint32)
+    h = 0
+    for w in words.tolist():
+        h = (h * int(bh_ref.PRIME) + w) & 0xFFFFFFFF
+    assert bh_ref.blockhash_np(data) == h
+    base = pow(int(bh_ref.PRIME), 1024, 1 << 32)
+    assert bh_ref.powers(5, base).tolist() == [
+        pow(base, e, 1 << 32) for e in range(4, -1, -1)]
 
 
 def test_blockhash_detects_corruption():
     data = bytearray(os.urandom(4096))
-    h = bh_ops.checksum(bytes(data))
+    h = bh_ops.checksum(bytes(data), interpret=True)
     data[100] ^= 0xFF
-    assert bh_ops.checksum(bytes(data)) != h
+    assert bh_ops.checksum(bytes(data), interpret=True) != h
 
 
 def test_blockhash_batch():
     blocks = [os.urandom(4096) for _ in range(5)]
-    got = bh_ops.checksum_batch(blocks)
+    got = bh_ops.checksum_batch(blocks, interpret=True)
     want = [bh_ref.blockhash_np(b) for b in blocks]
     assert got == want
 
 
-def test_compiler_params_compat_shim():
-    """One feature-detect for the whole kernel pack: every kernel uses the
-    SAME class object from ``_compat``, and it constructs with the kwargs
-    the kernels actually pass (a field rename breaks loudly here)."""
-    from repro.kernels import _compat
+@pytest.mark.parametrize("n", range(1, 66))
+def test_blockhash_batch_bucketed(n):
+    """Every journal-commit size pads to a bucket of 8..128 rows and still
+    hashes each block exactly like the host reference."""
+    rng = np.random.default_rng(n)
+    blocks = [rng.bytes(4096) for _ in range(n)]
+    rows, block_rows = bh_ops.bucket(n)
+    assert rows >= n and rows % 8 == 0 and rows in (8, 16, 32, 64, 128)
+    assert bh_ops.checksum_batch(blocks, interpret=True) == \
+        [bh_ref.blockhash_np(b) for b in blocks]
 
-    assert _compat.CompilerParams is not None
-    for mod in (fa_k, wkv_k, ssd_k):
-        assert mod._CompilerParams is _compat.CompilerParams
+
+def test_blockhash_batch_ragged_lengths():
+    blocks = [os.urandom(k) for k in (0, 1, 5, 4093, 4096, 9000)]
+    assert bh_ops.checksum_batch(blocks, interpret=True) == \
+        [bh_ref.blockhash_np(b) for b in blocks]
+
+
+@pytest.mark.parametrize("nbytes", [
+    4097, 3 * 4096 + 7, 64 * 4096, 300 * 4096 + 3])
+def test_blockhash_long_buffer_tiled(nbytes):
+    """A buffer longer than one row is hashed in 4 KiB rows (several grid
+    steps at 300 rows) and the row hashes combined — bit-exact."""
+    data = np.random.default_rng(nbytes).bytes(nbytes)
+    assert bh_ops.checksum(data, interpret=True) == bh_ref.blockhash_np(data)
+
+
+def test_blockhash_bucket_sizes():
+    assert [bh_ops.bucket(n) for n in (1, 63, 64, 65, 256, 257)] == [
+        (8, 8), (64, 64), (64, 64), (128, 128), (256, 256), (512, 256)]
+    for n in (1000, 8191, 27648, 1 << 20):
+        rows, block_rows = bh_ops.bucket(n)
+        assert rows % block_rows == 0 and n <= rows < n * 9 / 8 + 256
+
+
+def test_kernel_binding_raises_when_tpu_probe_fails(monkeypatch):
+    """On a TPU a kernel that fails its bind-time probe is an error, never
+    a silent CRC32 binding."""
+    from repro.core.services import kernel_binding
+    from repro.fs.blockdev import MemBlockDevice
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(bh_ops, "checksum", lambda data, interpret: 0)
+    monkeypatch.setattr(bh_ops, "checksum_batch",
+                        lambda blocks, interpret: [0] * len(blocks))
+    with pytest.raises(RuntimeError, match="probe"):
+        kernel_binding(MemBlockDevice(64))
+
+    def broken(data, interpret):
+        raise NotImplementedError("kernel does not lower")
+
+    monkeypatch.setattr(bh_ops, "checksum", broken)
+    with pytest.raises(NotImplementedError):
+        kernel_binding(MemBlockDevice(64))
+
+
+def test_kernel_binding_records_checksum_impl(monkeypatch):
+    from repro.core.services import kernel_binding, userspace_binding
+    from repro.fs.blockdev import MemBlockDevice
+
+    monkeypatch.delenv("REPRO_FORCE_PALLAS_CHECKSUM", raising=False)
+    assert kernel_binding(MemBlockDevice(64)).checksum_impl == "crc32"
+    assert userspace_binding(MemBlockDevice(64)).checksum_impl == "crc32"
+    monkeypatch.setenv("REPRO_FORCE_PALLAS_CHECKSUM", "1")
+    ks = kernel_binding(MemBlockDevice(64))
+    assert ks.checksum_impl == "blockhash-interpret"
+    block = os.urandom(4096)
+    assert ks.checksum_batch([block]) == [bh_ref.blockhash_np(block)]
+
+
+def test_compiler_params_compat_shim():
+    """Every kernel builds the installed jax's ``pltpu.CompilerParams``
+    directly, and it constructs with the kwargs the kernels pass (a field
+    rename breaks loudly here)."""
+    from jax.experimental.pallas import tpu as pltpu
+
     from repro.kernels.blockhash import kernel as bh_k
-    assert bh_k._CompilerParams is _compat.CompilerParams
+    for mod in (fa_k, wkv_k, ssd_k, bh_k):
+        assert mod.pltpu.CompilerParams is pltpu.CompilerParams
     for sem in (("parallel",), ("parallel", "parallel", "arbitrary"),
                 ("parallel", "parallel", "parallel", "arbitrary")):
-        _compat.CompilerParams(dimension_semantics=sem)
+        pltpu.CompilerParams(dimension_semantics=sem)

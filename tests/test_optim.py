@@ -105,13 +105,12 @@ def test_topk_ef_roundtrip():
 
 
 def test_compressed_psum_int8_single_shard():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
     from repro.launch.mesh import make_host_mesh
     mesh = make_host_mesh(1, 1)
     x = jax.random.normal(jax.random.PRNGKey(2), (64,))
-    f = shard_map(lambda v: C.compressed_psum_int8(v, "data"), mesh=mesh,
-                  in_specs=PS(), out_specs=PS(), check_rep=False)
+    f = jax.shard_map(lambda v: C.compressed_psum_int8(v, "data"), mesh=mesh,
+                      in_specs=PS(), out_specs=PS(), check_vma=False)
     y = f(x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x), atol=0.02)
 
