@@ -72,6 +72,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from repro.core.interface import Errno, FsError
+from repro.core.spans import span, traced
 from repro.distributed.resharding import (
     Index, ShardGrid, chunk_ops, index_volume, normalize_index,
     plan_target_shard, plan_volume, shift_ops,
@@ -142,13 +143,15 @@ class _WriteBehind:
                     self._err = e
 
     def put(self, batch) -> None:
-        if self._err is not None:
-            self.close()  # drains the worker and raises the write error
-        self._q.put(batch)
+        with span("ckpt.save.write"):
+            if self._err is not None:
+                self.close()  # drains the worker and raises the write error
+            self._q.put(batch)
 
     def close(self) -> None:
-        self._q.put(None)
-        self._t.join()
+        with span("ckpt.save.write"):
+            self._q.put(None)
+            self._t.join()
         if self._err is not None:
             err, self._err = self._err, None
             raise err
@@ -186,6 +189,7 @@ def _np_dtype(dtype_s: str) -> np.dtype:
     return np.dtype(dtype_s)
 
 
+@traced("ckpt.save.encode")
 def _serialize(arr: np.ndarray) -> bytes:
     # numpy can't serialize ml_dtypes (bf16 -> void): save a same-width
     # integer view and record the real dtype in the manifest.
@@ -218,12 +222,17 @@ def _resolve_grid(shape, leaf, sharding) -> ShardGrid:
     return grid if grid.n_shards > 1 else ShardGrid.trivial(shape)
 
 
+@traced("ckpt.save.d2h")
+def _to_host(x) -> np.ndarray:
+    return np.asarray(jax.device_get(x))
+
+
 def _shard_arrays(leaf, grid: ShardGrid):
     """Yield ``(j, shard ndarray)`` without materializing the full leaf
     when the leaf's device layout already matches the grid (the common
     save path); otherwise fall back to slicing a device_get'd copy."""
     if grid.n_shards == 1:
-        yield 0, np.asarray(jax.device_get(leaf))
+        yield 0, _to_host(leaf)
         return
     by_index = {}
     if isinstance(leaf, jax.Array):
@@ -238,10 +247,10 @@ def _shard_arrays(leaf, grid: ShardGrid):
         idx = grid.index(j)
         data = by_index.get(idx)
         if data is not None:
-            yield j, np.asarray(jax.device_get(data))
+            yield j, _to_host(data)
         else:
             if full is None:
-                full = np.asarray(jax.device_get(leaf))
+                full = _to_host(leaf)
             yield j, np.ascontiguousarray(
                 full[tuple(slice(lo, hi) for lo, hi in idx)])
 
@@ -253,6 +262,7 @@ def _first_leaf_names(root: str, gen: int):
     return (f"{root}/leaf_00000{sfx}.npy", f"{root}/leaf_00000_s000{sfx}.npy")
 
 
+@traced("ckpt.save")
 def save(view: PosixView, root: str, tree, *, step: int,
          checksum=None, extra: Optional[Dict] = None,
          shardings=None, pipeline_depth: Optional[int] = None) -> Dict:
@@ -334,6 +344,10 @@ def save(view: PosixView, root: str, tree, *, step: int,
                 path = f"{root}/leaf_{i:05d}_s{j:03d}{suffix}.npy"
                 items.append((path, raw))
                 pending_bytes += len(raw)
+                cks = None
+                if checksum:
+                    with span("ckpt.save.shard_hash"):
+                        cks = checksum(raw)
                 rec["shards"].append({
                     "path": path,
                     "coords": list(grid.coords(j)),
@@ -342,14 +356,15 @@ def save(view: PosixView, root: str, tree, *, step: int,
                     # stream sub-shard slices as offset reads without
                     # parsing headers
                     "data_off": len(raw) - shard.nbytes,
-                    "checksum": checksum(raw) if checksum else None,
+                    "checksum": cks,
                 })
                 if len(items) >= _BATCH_FILES \
                         or pending_bytes >= _BATCH_BYTES:
                     if sink is not None:
                         sink.put(items)
                     else:
-                        view.write_many(items)
+                        with span("ckpt.save.write"):
+                            view.write_many(items)
                     items, pending_bytes = [], 0
             manifest["leaves"].append(rec)
     except BaseException:
@@ -380,7 +395,6 @@ def save(view: PosixView, root: str, tree, *, step: int,
     # re-save — the old truncate-then-rewrite path had a window where
     # neither version did. Both properties are enumerated per crash point
     # by tests/test_crash_torture.py (v1 whole-leaf and v2 sharded saves).
-    raw_manifest = json.dumps(manifest).encode()
     if sink is not None:
         # join the write-behind lane — a failed shard write raises its
         # real errno HERE, before the manifest submission ever happens,
@@ -391,7 +405,20 @@ def save(view: PosixView, root: str, tree, *, step: int,
         finally:
             sink.close()
     elif items:
-        view.write_many(items)
+        with span("ckpt.save.write"):
+            view.write_many(items)
+    with span("ckpt.save.manifest"):
+        _commit_and_collect(view, root, manifest, old_exists)
+    return manifest
+
+
+def _commit_and_collect(view: PosixView, root: str, manifest: Dict,
+                        old_exists: bool) -> None:
+    """Make ``manifest`` the live one (directly, or by a tmp write and a
+    rename over the old one), then delete the shard files it no longer
+    names."""
+    manifest_path = f"{root}/{MANIFEST}"
+    raw_manifest = json.dumps(manifest).encode()
     try:
         if not old_exists:
             _commit_manifest(view, manifest_path, raw_manifest)
@@ -435,7 +462,6 @@ def save(view: PosixView, root: str, tree, *, step: int,
             view.unlink_many(stale, strict=False)
         except FsError:
             pass
-    return manifest
 
 
 def _commit_manifest(view: PosixView, path: str, raw: bytes) -> None:
@@ -831,7 +857,8 @@ def _run_inline(view: PosixView, tasks: List[_Task], timing: Dict) -> None:
     single-pass folded verification without prefetch."""
     for t in tasks:
         t0 = time.perf_counter()
-        raws = view.read_many(t.specs) if t.specs else []
+        with span("ckpt.restore.fetch"):
+            raws = view.read_many(t.specs) if t.specs else []
         timing["fetch_s"] += time.perf_counter() - t0
         total = sum(len(r) for r in raws)
         if t.peak is not None:
@@ -839,7 +866,8 @@ def _run_inline(view: PosixView, tasks: List[_Task], timing: Dict) -> None:
         kept = 0
         try:
             t0 = time.perf_counter()
-            kept = t.on_ready(raws) or 0
+            with span("ckpt.restore.assemble"):
+                kept = t.on_ready(raws) or 0
             timing["assemble_s"] += time.perf_counter() - t0
         finally:
             if t.peak is not None:
@@ -868,7 +896,8 @@ def _run_pipelined(view: PosixView, tasks: List[_Task], depth: int,
                 return
             try:
                 t0 = time.perf_counter()
-                raws = view.read_many(t.specs) if t.specs else []
+                with span("ckpt.restore.fetch"):
+                    raws = view.read_many(t.specs) if t.specs else []
                 timing["fetch_s"] += time.perf_counter() - t0
             except BaseException as e:  # noqa: BLE001 — re-raised on main
                 results.put((t, e, 0))
@@ -888,7 +917,8 @@ def _run_pipelined(view: PosixView, tasks: List[_Task], depth: int,
             kept = 0
             try:
                 t0 = time.perf_counter()
-                kept = t.on_ready(payload) or 0
+                with span("ckpt.restore.assemble"):
+                    kept = t.on_ready(payload) or 0
                 timing["assemble_s"] += time.perf_counter() - t0
             finally:
                 if t.peak is not None:
@@ -1354,6 +1384,7 @@ def _build_tasks(view: PosixView, recs, shardings, checksum,
     return tasks
 
 
+@traced("ckpt.restore")
 def load(view: PosixView, root: str, like_tree, *, checksum=None,
          checksum_batch=None, sharding_tree=None,
          stats: Optional[Dict] = None,

@@ -75,6 +75,7 @@ from typing import Any, Callable, Deque, Dict, Iterable, List, Optional
 from repro.core.interface import (BentoFilesystem, CompletionEntry, Errno,
                                   FS_OPS, FsError, SQE_LINK, SubmissionEntry,
                                   execute_batch, execute_multi_batch)
+from repro.core.spans import clock, interval, span
 
 _FS_REGISTRY: Dict[str, Callable[[], BentoFilesystem]] = {}
 
@@ -147,14 +148,23 @@ _FS_OPS = FS_OPS + ("submit_batch",)  # the table also carries the batch door
 class _PendingSubmission:
     """One submitter's staged entries waiting for a drain, plus the slot
     its completions (or the drain's implementation exception) come back
-    through."""
+    through. ``t0`` (set while a profile is taken) and ``started`` time
+    its wait for a drain to take it."""
 
-    __slots__ = ("entries", "comps", "error")
+    __slots__ = ("entries", "comps", "error", "t0", "started")
 
     def __init__(self, entries: List[SubmissionEntry]):
         self.entries = entries
         self.comps: Optional[List[CompletionEntry]] = None
         self.error: Optional[BaseException] = None
+        self.t0 = clock()
+        self.started: Optional[float] = None
+
+    def note_wait(self) -> None:
+        """Record the ``gate.wait`` span: from submission until a drain,
+        this thread's or another's, took it."""
+        if self.t0 is not None and self.started is not None:
+            interval("gate.wait", self.started - self.t0)
 
 
 class Mount:
@@ -213,11 +223,12 @@ class Mount:
             # crossing brackets this thread (see submit()); entering the
             # gate here could deadlock against a pending freeze
             return fn(*args, **kw)
-        self.gate.enter()
-        try:
-            return fn(*args, **kw)
-        finally:
-            self.gate.exit()
+        with span("gate.call"):
+            self.gate.enter()
+            try:
+                return fn(*args, **kw)
+            finally:
+                self.gate.exit()
 
     def submit(self, entries: Iterable[SubmissionEntry]) -> List[CompletionEntry]:
         """Batched dispatch, multi-submitter: each call is ONE submission.
@@ -269,6 +280,7 @@ class Mount:
                     and self._mq_draining:
                 self._mq_cv.wait()
             if sub.comps is not None or sub.error is not None:
+                sub.note_wait()
                 if sub.error is not None:
                     raise sub.error
                 return sub.comps
@@ -284,6 +296,7 @@ class Mount:
                 self._mq_draining = False
                 self._mq_drainer_tid = None
                 self._mq_cv.notify_all()
+        sub.note_wait()
         if sub.error is not None:
             raise sub.error
         return sub.comps
@@ -300,24 +313,29 @@ class Mount:
                 batch, self._mq_pending = self._mq_pending, []
             if not batch:
                 return carried
+            started = clock()
+            for s in batch:
+                s.started = started
             carried += len(batch)
             self.mq_drains += 1
-            self.gate.enter()
-            try:
-                segs = execute_multi_batch(self.table["submit_batch"],
-                                           [s.entries for s in batch],
-                                           pool=self._drain_pool)
-            except BaseException as e:
-                # an implementation exception (a bug — fs errors cross as
-                # errnos) poisons the whole drain: deliver it to every
-                # waiter and re-raise in the drainer, like scalar dispatch
-                with self._mq_cv:
-                    for s in batch:
-                        s.error = e
-                    self._mq_cv.notify_all()
-                raise
-            finally:
-                self.gate.exit()
+            with span("gate.drain"):
+                self.gate.enter()
+                try:
+                    segs = execute_multi_batch(self.table["submit_batch"],
+                                               [s.entries for s in batch],
+                                               pool=self._drain_pool)
+                except BaseException as e:
+                    # an implementation exception (a bug — fs errors cross
+                    # as errnos) poisons the whole drain: deliver it to
+                    # every waiter and re-raise in the drainer, like
+                    # scalar dispatch
+                    with self._mq_cv:
+                        for s in batch:
+                            s.error = e
+                        self._mq_cv.notify_all()
+                    raise
+                finally:
+                    self.gate.exit()
             with self._mq_cv:
                 for s, comps in zip(batch, segs):
                     s.comps = comps

@@ -24,6 +24,7 @@ from typing import Callable, List, Optional
 from repro.core.capability import (BlockDeviceCap, CapabilityError,
                                    SuperBlockCap, mint_blockdev,
                                    mint_superblock)
+from repro.core.spans import span
 from repro.fs.blockdev import BlockDevice
 from repro.fs.buffercache import BufferCache, BufferHead
 
@@ -173,8 +174,9 @@ def kernel_binding(dev: BlockDevice, **kw) -> KernelServices:
 
     interpret = not on_tpu
     probe = bytes(range(256)) * 17  # spans two rows
-    got = [bh_ops.checksum(probe, interpret=interpret)] + \
-        bh_ops.checksum_batch([probe[:4096]], interpret=interpret)
+    with span("services.probe"):
+        got = [bh_ops.checksum(probe, interpret=interpret)] + \
+            bh_ops.checksum_batch([probe[:4096]], interpret=interpret)
     want = [blockhash_np(probe), blockhash_np(probe[:4096])]
     if got != want:
         raise RuntimeError(

@@ -18,6 +18,7 @@ import collections
 import threading
 from typing import Dict, List, Optional
 
+from repro.core.spans import span
 from repro.fs.blockdev import BlockDevice
 
 
@@ -101,7 +102,8 @@ class BufferCache:
             buf = self._blocks.get(blockno)
             if buf is None:
                 self.misses += 1
-                buf = bytearray(self.dev.read_block(blockno))
+                with span("cache.fill"):
+                    buf = bytearray(self.dev.read_block(blockno))
                 self._insert(blockno, buf)
             else:
                 self.hits += 1
@@ -143,7 +145,9 @@ class BufferCache:
             missing = [b for b in dict.fromkeys(rest)
                        if b not in self._blocks]
             try:
-                prefetched = dict(zip(missing, self.dev.read_many(missing)))
+                with span("cache.fill"):
+                    prefetched = dict(zip(missing,
+                                          self.dev.read_many(missing)))
             except BaseException:
                 for bh in out:  # clean (never dirtied) — just unpin
                     self._release_locked(bh)

@@ -73,6 +73,7 @@ from typing import Dict, List, Optional
 
 from repro.core.capability import SuperBlockCap
 from repro.core.interface import Errno, FsError
+from repro.core.spans import count, span, traced
 from repro.fs.layout import BSIZE, SuperBlock
 
 _HDR_FMT_HEAD = "<III"  # magic, n, seq
@@ -126,7 +127,6 @@ class Journal:
         # staging; set by the fs at init
         self.rollback_listener = None
         self.commits = 0
-        self.blocks_logged = 0
         self.chains = 0          # chain reservations taken
         self.chain_precommits = 0  # commits forced to make room for a chain
 
@@ -309,44 +309,54 @@ class Journal:
     def _commit_locked(self) -> None:
         if not self._pending:
             return
+        with span("journal.commit"):
+            self._write_commit()
+
+    def _write_commit(self) -> None:
         items = sorted(self._pending.items())
         assert len(items) <= self.capacity
+        count("journal.commit_blocks", len(items))
         # 1) write data blocks into the journal area
-        for i, (_home, data) in enumerate(items):
-            with self.ks.sb_getblk_zero(self.sb_cap, self.sb.logstart + 1 + i) as bh:
-                bh.data()[:] = data
-                self.ks.bwrite_sync(self.sb_cap, bh)
-        # 2) commit record (header with checksums) — the commit point
-        # (batched: one Pallas kernel launch per transaction)
-        sums = self.ks.checksum_batch([data for _h, data in items])
-        hdr = struct.pack(_HDR_FMT_HEAD, _HDR_MAGIC, len(items), self._seq)
-        for (home, _data), cks in zip(items, sums):
-            hdr += struct.pack("<II", home, cks)
-        with self.ks.sb_getblk_zero(self.sb_cap, self.sb.logstart) as bh:
-            bh.data()[: len(hdr)] = hdr
-            self.ks.bwrite_sync(self.sb_cap, bh)
-        # 3) install to home locations
-        if self.batched_install:
-            # writepages-style: stage dirty, one sorted batched flush.
-            for home, data in items:
-                with self.ks.sb_getblk_zero(self.sb_cap, home) as bh:
-                    bh.data()[:] = data
-                    bh.mark_dirty()
-            self.ks.flush(self.sb_cap, [h for h, _ in items])
-        else:
-            for home, data in items:
-                with self.ks.sb_getblk_zero(self.sb_cap, home) as bh:
+        with span("journal.commit.log_write"):
+            for i, (_home, data) in enumerate(items):
+                with self.ks.sb_getblk_zero(self.sb_cap,
+                                            self.sb.logstart + 1 + i) as bh:
                     bh.data()[:] = data
                     self.ks.bwrite_sync(self.sb_cap, bh)
+        # 2) commit record (header with checksums) — the commit point
+        # (batched: one Pallas kernel launch per transaction)
+        with span("journal.commit.hash"):
+            sums = self.ks.checksum_batch([data for _h, data in items])
+            hdr = struct.pack(_HDR_FMT_HEAD, _HDR_MAGIC, len(items),
+                              self._seq)
+            for (home, _data), cks in zip(items, sums):
+                hdr += struct.pack("<II", home, cks)
+            with self.ks.sb_getblk_zero(self.sb_cap, self.sb.logstart) as bh:
+                bh.data()[: len(hdr)] = hdr
+                self.ks.bwrite_sync(self.sb_cap, bh)
+        # 3) install to home locations
+        with span("journal.commit.install"):
+            if self.batched_install:
+                # writepages-style: stage dirty, one sorted batched flush.
+                for home, data in items:
+                    with self.ks.sb_getblk_zero(self.sb_cap, home) as bh:
+                        bh.data()[:] = data
+                        bh.mark_dirty()
+                self.ks.flush(self.sb_cap, [h for h, _ in items])
+            else:
+                for home, data in items:
+                    with self.ks.sb_getblk_zero(self.sb_cap, home) as bh:
+                        bh.data()[:] = data
+                        self.ks.bwrite_sync(self.sb_cap, bh)
         # 4) clear the header
         with self.ks.sb_getblk_zero(self.sb_cap, self.sb.logstart) as bh:
             self.ks.bwrite_sync(self.sb_cap, bh)
         self.commits += 1
-        self.blocks_logged += len(items)
         self._seq += 1
         self._pending.clear()
 
     # --- recovery -------------------------------------------------------------------
+    @traced("journal.recover")
     def recover(self) -> int:
         """Replay a committed transaction found in the journal. Returns the
         number of blocks installed (0 if log was clean or torn)."""

@@ -18,6 +18,7 @@ from typing import Any, Callable, Dict
 from repro.core.interface import FS_OPS as _FS_OPS, execute_batch
 from repro.core.registry import Mount, mount as bento_mount
 from repro.core.services import kernel_binding, userspace_binding
+from repro.core.spans import traced
 from repro.fs.blockdev import LazyBlockDevice, MemBlockDevice
 from repro.fs.ext4like import Ext4LikeFileSystem
 from repro.fs.fusebridge import FuseMount
@@ -146,6 +147,7 @@ def make_mount(kind: str, n_blocks: int = 16384, *,
     raise KeyError(kind)
 
 
+@traced("mount.remount")
 def remount(dev: MemBlockDevice) -> MountedFs:
     """Mount a ``bento`` device that already holds a file system, cold: a
     fresh binding, cache and module, no mkfs, and the module's init

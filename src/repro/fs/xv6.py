@@ -58,6 +58,7 @@ from repro.core.capability import SuperBlockCap
 from repro.core.interface import (Attr, BentoFilesystem, CompletionEntry,
                                   Errno, FileKind, FsError, PrevResult,
                                   ROOT_INO, SubmissionEntry)
+from repro.core.spans import count, span
 from repro.fs import layout as L
 from repro.fs.blockstore import BlockStore, DEDUP_TABLE_NAME
 from repro.fs.journal import Journal, JournalFull
@@ -146,12 +147,14 @@ class _SharedExclusiveLock:
             if self._writer == threading.get_ident():
                 self._depth += 1  # exclusive is stronger: just nest
                 return
-            while self._writer is not None or self._waiting:
-                self._parked += 1
-                try:
-                    self._cond.wait()
-                finally:
-                    self._parked -= 1
+            if self._writer is not None or self._waiting:
+                with span("lock.wait"):
+                    while self._writer is not None or self._waiting:
+                        self._parked += 1
+                        try:
+                            self._cond.wait()
+                        finally:
+                            self._parked -= 1
             self._readers += 1
         finally:
             lk.release()
@@ -186,12 +189,13 @@ class _SharedExclusiveLock:
                 return
             self._waiting += 1
             try:
-                while self._writer is not None or self._readers:
-                    self._parked += 1
-                    try:
-                        self._cond.wait()
-                    finally:
-                        self._parked -= 1
+                with span("lock.wait"):
+                    while self._writer is not None or self._readers:
+                        self._parked += 1
+                        try:
+                            self._cond.wait()
+                        finally:
+                            self._parked -= 1
             finally:
                 self._waiting -= 1
             self._writer = tid
@@ -377,7 +381,7 @@ class Xv6FileSystem(BentoFilesystem):
         self._icache: Dict[int, L.DiskInode] = {}
         self._free_hint = 0
         self._free_inode_hint = 2
-        self.stats = {"ops": 0, "commits_forced": 0}
+        self.stats = {"ops": 0}
         self._blockstore: Optional[BlockStore] = None
         self._current_submitter = None  # stamped per run by submit_batch
         # dedup widens the per-write metadata footprint (CoW copy block +
@@ -468,7 +472,6 @@ class Xv6FileSystem(BentoFilesystem):
         if self.journal.in_chain_here:
             return
         if len(self.journal._pending) + MAXOP_BLOCKS >= self.journal.capacity:
-            self.stats["commits_forced"] += 1
             self.journal.commit()
         self.journal.begin_op_scope()  # overflow rolls back to this point
 
@@ -494,7 +497,6 @@ class Xv6FileSystem(BentoFilesystem):
             self.journal.commit()
         elif len(self.journal._pending) >= int(
                 self.journal.capacity * self.opts.commit_threshold):
-            self.stats["commits_forced"] += 1
             self.journal.commit()
 
     # --- chain-scoped reservation (SQE_LINK chains as one journal txn) --------------
@@ -1188,6 +1190,7 @@ class Xv6FileSystem(BentoFilesystem):
                 names.setdefault(name, (bn, off, e_ino))
             else:
                 holes.append((bn, off))
+        self._count_lookup(pdi.size)
         return {"names": names, "holes": holes}
 
     def _create_many_common(self, reqs, kind: int) -> List:
@@ -1330,8 +1333,18 @@ class Xv6FileSystem(BentoFilesystem):
         # inspects them through dir_entry_state instead
         for bn, off, e_ino, e_name in self._dir_entries(dino, di):
             if e_ino != 0 and e_ino != L.WHITEOUT_INO and e_name == name:
+                self._count_lookup(bn * L.BSIZE + off + L.DIRENT_SIZE)
                 return bn, off, e_ino
+        self._count_lookup(di.size)
         return None
+
+    @staticmethod
+    def _count_lookup(scanned_bytes: int) -> None:
+        """One directory search and the entries it read: the slots from
+        the start of the directory to the hit, or to its end (a miss, or
+        a batch's whole-directory scan)."""
+        count("dir.lookups")
+        count("dir.entries_scanned", -(-scanned_bytes // L.DIRENT_SIZE))
 
     def _dirlink(self, dino: int, name: str, ino: int) -> None:
         di = self._iget(dino)

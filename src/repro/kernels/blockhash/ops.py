@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import span, traced
 from repro.kernels.blockhash import kernel as K
 from repro.kernels.blockhash import ref
 
@@ -69,6 +70,7 @@ def _place(row: np.ndarray, data) -> None:
     row[start:start + len(data)] = np.frombuffer(data, np.uint8)
 
 
+@traced("blockhash.buffer")
 def checksum(data, *, interpret: bool = False) -> int:
     """Hash one buffer of any length (a block, a whole shard file)."""
     rows, block_rows = bucket(max(1, -(-len(data) // ROW_BYTES)))
@@ -88,15 +90,16 @@ def checksum_batch(blocks, *, interpret: bool = False) -> list:
         return []
     if any(len(b) > ROW_BYTES for b in blocks):
         return [checksum(b, interpret=interpret) for b in blocks]
-    n = len(blocks)
-    rows, block_rows = bucket(n)
-    buf = np.zeros((rows, ROW_BYTES), np.uint8)
-    if all(len(b) == ROW_BYTES for b in blocks):
-        buf[:n] = np.frombuffer(b"".join(blocks), np.uint8).reshape(
-            n, ROW_BYTES)
-    else:
-        for row, b in zip(buf, blocks):
-            _place(row, b)
-    out = hash_rows(buf.view(np.uint32), _pows(), block_rows=block_rows,
-                    interpret=interpret)
-    return np.asarray(out)[:n].tolist()
+    with span("blockhash.batch"):
+        n = len(blocks)
+        rows, block_rows = bucket(n)
+        buf = np.zeros((rows, ROW_BYTES), np.uint8)
+        if all(len(b) == ROW_BYTES for b in blocks):
+            buf[:n] = np.frombuffer(b"".join(blocks), np.uint8).reshape(
+                n, ROW_BYTES)
+        else:
+            for row, b in zip(buf, blocks):
+                _place(row, b)
+        out = hash_rows(buf.view(np.uint32), _pows(), block_rows=block_rows,
+                        interpret=interpret)
+        return np.asarray(out)[:n].tolist()
