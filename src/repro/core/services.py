@@ -24,7 +24,7 @@ from typing import Callable, List, Optional
 from repro.core.capability import (BlockDeviceCap, CapabilityError,
                                    SuperBlockCap, mint_blockdev,
                                    mint_superblock)
-from repro.core.spans import span
+from repro.core.spans import count, span
 from repro.fs.blockdev import BlockDevice
 from repro.fs.buffercache import BufferCache, BufferHead
 
@@ -92,7 +92,24 @@ class KernelServices:
         with self._counter_lock:
             self.counters["bread_many_calls"] += 1
             self.counters["bread_many_blocks"] += len(blocknos)
+        count("cache.bread_many_blocks", len(blocknos))
         return self._cache_of(sb).bread_many(blocknos, fetched=fetched)
+
+    def sb_bread_bulk(self, sb: SuperBlockCap, blocknos, out) -> None:
+        """Bulk read for a batch the cache cannot hold: fills ``out``, an
+        ``(n, block_size)`` uint8 view, row by row in request order —
+        cached blocks from the cache, the rest from one device call. No
+        BufferHead, no ref, no cache insertion."""
+        cache = self._cache_of(sb)
+        count("cache.bulk_blocks", len(blocknos))
+        cache.read_into(blocknos, out)
+
+    def sb_cache_capacity(self, sb: SuperBlockCap) -> int:
+        return self._cache_of(sb).capacity
+
+    def sb_n_uncached(self, sb: SuperBlockCap, blocknos) -> int:
+        """How many of the distinct ``blocknos`` the cache does not hold."""
+        return self._cache_of(sb).n_uncached(blocknos)
 
     def sb_brelse_many(self, sb: SuperBlockCap,
                        heads: List[BufferHead]) -> None:

@@ -80,6 +80,17 @@ class BlockDevice:
         its miss runs here."""
         return [self.read_block(b) for b in blocknos]
 
+    def read_many_into(self, blocknos, out: np.ndarray) -> None:
+        """Read ``blocknos`` into the rows of ``out``, an ``(n,
+        block_size)`` uint8 array, in order. The base implementation is
+        one ``read_many`` call, so a device with its own batch path keeps
+        it (``LazyBlockDevice``: one provider round-trip, crash-ordered
+        materialization); ``MemBlockDevice`` gathers straight into
+        ``out``. The buffer cache's bulk reads route their misses here."""
+        for row, data in zip(out, self.read_many(
+                [int(b) for b in blocknos])):
+            row[:] = np.frombuffer(data, dtype=np.uint8)
+
     def write_block(self, blockno: int, data: bytes) -> None:
         raise NotImplementedError
 
@@ -133,6 +144,16 @@ class MemBlockDevice(BlockDevice):
         with self._lock:
             self.reads += 1
             return self._data[blockno].tobytes()
+
+    def read_many_into(self, blocknos, out: np.ndarray) -> None:
+        """One fancy-index gather under one lock: no per-block bytes."""
+        idx = np.asarray(blocknos, dtype=np.int64)
+        if idx.size and not (0 <= idx.min() and idx.max() < self.n_blocks):
+            bad = idx[(idx < 0) | (idx >= self.n_blocks)][0]
+            self._check(int(bad))
+        with self._lock:
+            self.reads += idx.size
+            np.take(self._data, idx, axis=0, out=out, mode="clip")
 
     def write_block(self, blockno: int, data: bytes) -> None:
         self._check(blockno, data)

@@ -18,6 +18,8 @@ import collections
 import threading
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from repro.core.spans import span
 from repro.fs.blockdev import BlockDevice
 
@@ -142,8 +144,11 @@ class BufferCache:
             # call, so a lazy device materializes the whole run in a
             # single provider round-trip instead of one fetch per block
             rest = blocknos[len(out):]
-            missing = [b for b in dict.fromkeys(rest)
-                       if b not in self._blocks]
+            # the run's cached blocks, kept so that inserting its misses
+            # cannot evict one before its head is taken (only clean blocks
+            # are evicted, so the kept buffer is still current)
+            held = {b: self._blocks[b] for b in rest if b in self._blocks}
+            missing = [b for b in dict.fromkeys(rest) if b not in held]
             try:
                 with span("cache.fill"):
                     prefetched = dict(zip(missing,
@@ -154,7 +159,11 @@ class BufferCache:
                 raise
             for blockno in rest:
                 buf = self._blocks.get(blockno)
-                if buf is None:
+                if buf is None and blockno in held:  # evicted by this run
+                    self.hits += 1
+                    buf = held[blockno]
+                    self._insert(blockno, buf)
+                elif buf is None:
                     self.misses += 1
                     buf = bytearray(prefetched[blockno])
                     self._insert(blockno, buf)
@@ -166,6 +175,38 @@ class BufferCache:
                 self._refs[blockno] += 1
                 out.append(BufferHead(blockno, buf, self))
         return out
+
+    def n_uncached(self, blocknos: np.ndarray) -> int:
+        """How many of ``blocknos`` (distinct) the cache does not hold."""
+        with self._lock:
+            return int(blocknos.size - np.isin(blocknos, self._keys()).sum())
+
+    def read_into(self, blocknos, out: np.ndarray) -> None:
+        """Fill row i of ``out`` (an ``(n, block_size)`` uint8 array) with
+        block ``blocknos[i]``: the bulk read of a batch too large to stay
+        cached. Cached blocks, clean or dirty (a dirty one is newer than
+        the device), come from the cache; all others from ONE device call.
+        Nothing is inserted or pinned and no hit or miss is counted, so
+        the cache keeps what it held."""
+        blocknos = np.asarray(blocknos, dtype=np.int64)
+        with self._lock:
+            # dirty blocks are never evicted, so _blocks holds them all
+            hit = np.isin(blocknos, self._keys())
+            miss = np.flatnonzero(~hit)
+            if miss.size:
+                with span("cache.fill"):
+                    if miss.size == blocknos.size:
+                        self.dev.read_many_into(blocknos, out)
+                    else:
+                        rows = np.empty((miss.size, out.shape[1]), np.uint8)
+                        self.dev.read_many_into(blocknos[miss], rows)
+                        out[miss] = rows
+            for i in np.flatnonzero(hit).tolist():
+                out[i] = np.frombuffer(self._blocks[int(blocknos[i])],
+                                       dtype=np.uint8)
+
+    def _keys(self) -> np.ndarray:
+        return np.fromiter(self._blocks, np.int64, len(self._blocks))
 
     def getblk_zero(self, blockno: int) -> BufferHead:
         """Get a block without reading it (about to be fully overwritten)."""
