@@ -61,6 +61,7 @@ from __future__ import annotations
 
 import io
 import json
+import math
 import os
 import queue
 import threading
@@ -72,7 +73,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from repro.core.interface import Errno, FsError
-from repro.core.spans import span, traced
+from repro.core.spans import count, span, traced
 from repro.distributed.resharding import (
     Index, ShardGrid, chunk_ops, index_volume, normalize_index,
     plan_target_shard, plan_volume, shift_ops,
@@ -409,6 +410,8 @@ def save(view: PosixView, root: str, tree, *, step: int,
             view.write_many(items)
     with span("ckpt.save.manifest"):
         _commit_and_collect(view, root, manifest, old_exists)
+    count("ckpt.save.shard_files",
+          sum(len(rec["shards"]) for rec in manifest["leaves"]))
     return manifest
 
 
@@ -552,6 +555,35 @@ class _Peak:
             self.cur -= n
 
 
+def _read_many(view: PosixView, specs) -> List[bytes]:
+    """``view.read_many`` on a restore's behalf, counting the bytes the
+    file system returned and the offset-read runs (``(path, off, n)``
+    specs) among ``specs``."""
+    raws = view.read_many(specs)
+    count("ckpt.restore.bytes_read", sum(len(r) for r in raws))
+    runs = [spec[2] for spec in specs if not isinstance(spec, str)]
+    if runs:
+        count("ckpt.restore.runs", len(runs))
+        count("ckpt.restore.run_bytes", sum(runs))
+    return raws
+
+
+def _put(arr: np.ndarray, target=None):
+    """``jax.device_put`` of restored bytes (a whole leaf, or one target
+    shard onto one device), counting the bytes that land on devices,
+    once per device a replica lands on. The span holds the host's part
+    of the transfer; the copy may finish after it returns."""
+    with span("ckpt.restore.put"):
+        out = jax.device_put(arr, target)
+    if isinstance(target, NamedSharding):
+        n = (len(target.addressable_devices) * arr.dtype.itemsize
+             * math.prod(target.shard_shape(arr.shape)))
+    else:
+        n = arr.nbytes
+    count("ckpt.restore.bytes_placed", n)
+    return out
+
+
 def _verify_shards(view: PosixView, srecs, src_idx, need, checksum,
                    peak: _Peak, itemsize: int, full_bytes: int):
     """Whole-file checksum pass over the shards a restore will touch,
@@ -568,7 +600,7 @@ def _verify_shards(view: PosixView, srecs, src_idx, need, checksum,
                                       and len(chunk) < _BATCH_FILES)):
             pend += est[todo[0]]
             chunk.append(todo.pop(0))
-        raws = view.read_many([srecs[j]["path"] for j in chunk])
+        raws = _read_many(view, [srecs[j]["path"] for j in chunk])
         total = sum(len(r) for r in raws)
         peak.add(total)
         bad = None
@@ -641,7 +673,7 @@ def _fill_buffer(view: PosixView, buf: np.ndarray, ops, srecs, src_idx,
         nonlocal specs, places, pend, crossings
         if not specs:
             return
-        raws = view.read_many(specs)
+        raws = _read_many(view, specs)
         crossings += 1
         total = sum(len(r) for r in raws)
         peak.add(total)
@@ -659,7 +691,7 @@ def _fill_buffer(view: PosixView, buf: np.ndarray, ops, srecs, src_idx,
         if "data_off" not in s:
             # no payload offset recorded (hand-written manifest): fall
             # back to one whole-file read for this shard
-            raw = view.read_file(s["path"])
+            raw = _read_many(view, [s["path"]])[0]
             crossings += 1
             peak.add(len(raw))
             arr = np.load(io.BytesIO(raw)).view(dtype)
@@ -681,7 +713,8 @@ def _fill_buffer(view: PosixView, buf: np.ndarray, ops, srecs, src_idx,
                     base, done = s["data_off"] + off, 0
                     while done < nbytes:
                         n = min(step, nbytes - done)
-                        raw = view.read_many([(s["path"], base + done, n)])[0]
+                        raw = _read_many(
+                            view, [(s["path"], base + done, n)])[0]
                         crossings += 1
                         peak.add(len(raw))
                         e0 = done // dtype.itemsize
@@ -741,7 +774,7 @@ def _restore_streamed(view: PosixView, rec: Dict, target, checksum,
         buf = np.empty(shape, dtype)
         peak.add(buf.nbytes)
         _fill_buffer(view, buf, ops, srecs, src_idx, dtype, peak)
-        leaf = jax.device_put(buf)
+        leaf = _put(buf)
         peak.sub(buf.nbytes)
         return leaf
     if isinstance(target, NamedSharding):
@@ -776,12 +809,11 @@ def _restore_streamed(view: PosixView, rec: Dict, target, checksum,
         peak.add(buf.nbytes)
         _fill_buffer(view, buf, ops, srecs, src_idx, dtype, peak)
         if groups[di] is None:
-            leaf = jax.device_put(buf) if target is None \
-                else jax.device_put(buf, target)
+            leaf = _put(buf, target)
             peak.sub(buf.nbytes)
             return leaf
         for dev in groups[di]:
-            arrays.append(jax.device_put(buf, dev))
+            arrays.append(_put(buf, dev))
         peak.sub(buf.nbytes)
     return jax.make_array_from_single_device_arrays(shape, target, arrays)
 
@@ -858,7 +890,7 @@ def _run_inline(view: PosixView, tasks: List[_Task], timing: Dict) -> None:
     for t in tasks:
         t0 = time.perf_counter()
         with span("ckpt.restore.fetch"):
-            raws = view.read_many(t.specs) if t.specs else []
+            raws = _read_many(view, t.specs) if t.specs else []
         timing["fetch_s"] += time.perf_counter() - t0
         total = sum(len(r) for r in raws)
         if t.peak is not None:
@@ -897,7 +929,7 @@ def _run_pipelined(view: PosixView, tasks: List[_Task], depth: int,
             try:
                 t0 = time.perf_counter()
                 with span("ckpt.restore.fetch"):
-                    raws = view.read_many(t.specs) if t.specs else []
+                    raws = _read_many(view, t.specs) if t.specs else []
                 timing["fetch_s"] += time.perf_counter() - t0
             except BaseException as e:  # noqa: BLE001 — re-raised on main
                 results.put((t, e, 0))
@@ -1256,7 +1288,7 @@ def _leaf_tasks(view: PosixView, rec: Dict, target, checksum,
 
             def finalize(b, devs=groups[di], last=(u_i == len(dis) - 1)):
                 for dev in devs:
-                    arrays.append(jax.device_put(b, dev))
+                    arrays.append(_put(b, dev))
                 if last:
                     done(jax.make_array_from_single_device_arrays(
                         shape, target, arrays))
@@ -1284,7 +1316,7 @@ def _leaf_tasks(view: PosixView, rec: Dict, target, checksum,
         tasks += _unit_tasks(view, srecs, src_idx, dtype, ops, full,
                              depth, peak, checksum, checksum_batch,
                              verified,
-                             lambda b: done(jax.device_put(b)))
+                             lambda b: done(_put(b)))
     else:
         ops = plan_target_shard(src_idx, full)
         check(ops, full)
@@ -1293,8 +1325,7 @@ def _leaf_tasks(view: PosixView, rec: Dict, target, checksum,
         tasks += _unit_tasks(
             view, srecs, src_idx, dtype, ops, full, depth, peak,
             checksum, checksum_batch, verified,
-            lambda b: done(jax.device_put(b) if target is None
-                           else jax.device_put(b, target)))
+            lambda b: done(_put(b, target)))
     return tasks
 
 
@@ -1344,12 +1375,10 @@ def _build_tasks(view: PosixView, recs, shardings, checksum,
                     raise IOError(f"shape mismatch in {s['path']}")
                 peak.add(arr.nbytes)
                 target = shardings[i]
-                if target is None or isinstance(target, ShardGrid):
-                    # a 1-shard source with a (possibly uneven) grid
-                    # target has no device placement to honor
-                    out[i] = jax.device_put(arr)
-                else:
-                    out[i] = jax.device_put(arr, target)
+                # a 1-shard source with a (possibly uneven) grid target
+                # has no device placement to honor
+                out[i] = _put(arr, None if isinstance(target, ShardGrid)
+                              else target)
                 peak.sub(len(raw) + arr.nbytes)
                 note(i, rec, peak, streamed=False)
 
@@ -1464,7 +1493,8 @@ def _load_serial(view: PosixView, recs, shardings, checksum, out,
     pend: List[int] = []
 
     def flush_simple():
-        raws = view.read_many([recs[i]["shards"][0]["path"] for i in pend])
+        raws = _read_many(view,
+                          [recs[i]["shards"][0]["path"] for i in pend])
         for i, raw in zip(pend, raws):
             rec, s = recs[i], recs[i]["shards"][0]
             peak = _Peak()
@@ -1480,12 +1510,10 @@ def _load_serial(view: PosixView, recs, shardings, checksum, out,
                 raise IOError(f"shape mismatch in {s['path']}")
             peak.add(arr.nbytes)
             target = shardings[i]
-            if target is None or isinstance(target, ShardGrid):
-                # a 1-shard source with a (possibly uneven) grid target
-                # has no device placement to honor
-                out[i] = jax.device_put(arr)
-            else:
-                out[i] = jax.device_put(arr, target)
+            # a 1-shard source with a (possibly uneven) grid target
+            # has no device placement to honor
+            out[i] = _put(arr, None if isinstance(target, ShardGrid)
+                          else target)
             peak.sub(len(raw) + arr.nbytes)
             note(i, rec, peak, streamed=False)
         pend.clear()

@@ -38,13 +38,18 @@ def _init_state(pspecs, ospecs, run: RunConfig, rng):
             P.materialize(ospecs, rng, dtype="float32"))
 
 
+def _state_shapes(pspecs, ospecs, run: RunConfig):
+    """Shapes and dtypes of the state ``_init_state`` makes, without
+    materializing it."""
+    return jax.eval_shape(functools.partial(_init_state, pspecs, ospecs, run),
+                          jax.random.PRNGKey(0))
+
+
 def state_nbytes(cfg: ModelConfig, run: RunConfig) -> int:
     """Bytes of a Trainer's parameters plus optimizer state — what one
     checkpoint stores — from their specs, without materializing them."""
     pspecs = lm.param_specs(cfg)
-    shapes = jax.eval_shape(
-        functools.partial(_init_state, pspecs, adamw_init_specs(pspecs, run),
-                          run), jax.random.PRNGKey(0))
+    shapes = _state_shapes(pspecs, adamw_init_specs(pspecs, run), run)
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
 
 
@@ -74,7 +79,9 @@ class Trainer(BentoModule):
         self.recoveries = 0
         self.data = data or SyntheticLM(cfg, global_batch, seq_len, seed=seed)
         self._build(mesh, ruleset)
-        self._init_state()
+        # made from the seed when first read (see ``params``), so a job
+        # that restarts from a checkpoint never materializes random state
+        self._params = self._opt_state = None
         self.step_idx = 0
         self.last_restore_stats: Dict[str, Any] = {}
         self._prefetch: Optional[Prefetcher] = None
@@ -87,6 +94,8 @@ class Trainer(BentoModule):
                     else ShardingCtx.null())
         self.pspecs = lm.param_specs(self.cfg)
         self.ospecs = adamw_init_specs(self.pspecs, self.run)
+        # what a restore fills in: a restarted job knows only these
+        self._shapes = _state_shapes(self.pspecs, self.ospecs, self.run)
         fn = make_train_step(self.cfg, self.run, self.ctx, self.global_batch)
         if mesh is not None:
             from repro.launch.programs import _ns_tree
@@ -107,7 +116,36 @@ class Trainer(BentoModule):
         init = jax.jit(functools.partial(_init_state, self.pspecs,
                                          self.ospecs, self.run),
                        out_shardings=shardings)
-        self.params, self.opt_state = init(jax.random.PRNGKey(self.seed))
+        self._params, self._opt_state = init(jax.random.PRNGKey(self.seed))
+
+    @property
+    def params(self):
+        if self._params is None:
+            self._init_state()
+        return self._params
+
+    @params.setter
+    def params(self, value) -> None:
+        self._params = value
+
+    @property
+    def opt_state(self):
+        if self._opt_state is None:
+            self._init_state()
+        return self._opt_state
+
+    @opt_state.setter
+    def opt_state(self, value) -> None:
+        self._opt_state = value
+
+    def drop_state(self) -> None:
+        """Free the state in HBM, as a killed job loses it: only what a
+        checkpoint holds comes back (``restore_checkpoint``); reading the
+        state before that makes a fresh one from the seed."""
+        for x in jax.tree.leaves((self._params, self._opt_state)):
+            if isinstance(x, jax.Array):
+                x.delete()
+        self._params = self._opt_state = None
 
     # --- stepping ------------------------------------------------------------------
     def _fetch(self, step: int) -> Dict[str, np.ndarray]:
@@ -207,7 +245,9 @@ class Trainer(BentoModule):
         if step is None:
             return False
         root = f"{self.ckpt_root}/step_{step:08d}"
-        like = {"params": self.params, "opt": self.opt_state}
+        # the state in HBM, if any, is neither read nor needed
+        params, opt = self._shapes
+        like = {"params": params, "opt": opt}
         self.last_restore_stats = {}
         ks = self._ckpt_services()
         tree, _mf = ckpt.load(

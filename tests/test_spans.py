@@ -159,3 +159,43 @@ def test_journal_commits_count_their_blocks_and_submissions_wait(profile):
                 for s in ("log_write", "hash", "install"))
     assert commit["self_s"] == pytest.approx(commit["total_s"] - steps,
                                              abs=1e-9)
+
+
+@pytest.mark.parametrize("depth", [0, 1, 2])
+def test_checkpoint_counters_on_a_resharding_restore(profile, depth):
+    """A save cut 4 ways and a restore onto a 2 x 2 grid of another cut:
+    the save counts the manifest's shard files, the restore the bytes it
+    placed (the target cells') and read (at least those)."""
+    import numpy as np
+
+    from repro import checkpoint as ckpt
+    from repro.distributed.resharding import ShardGrid, index_volume
+    from repro.fs.mounts import make_mount
+
+    mf = make_mount("bento")
+    rng = np.random.default_rng(0)
+    tree = {"w": rng.normal(size=(8, 16)).astype(np.float32),
+            "b": rng.normal(size=(16,)).astype(np.float32)}
+    src = {"w": ShardGrid.from_spec((8, 16), (None, "data"), {"data": 4}),
+           "b": ShardGrid.from_spec((16,), ("data",), {"data": 4})}
+    dst = {"w": ShardGrid.from_spec((8, 16), ("model", "data"),
+                                    {"data": 2, "model": 2}),
+           "b": ShardGrid.from_spec((16,), ("data",), {"data": 2})}
+    cks = mf.services.checksum
+    with profile():
+        manifest = ckpt.save(mf.view, "/ck", tree, step=1, checksum=cks,
+                             shardings=src)
+    assert spans.snapshot()["counters"]["ckpt.save.shard_files"] == sum(
+        len(r["shards"]) for r in manifest["leaves"]) == 8
+    with profile():
+        back, _ = ckpt.load(mf.view, "/ck", tree, checksum=cks,
+                            sharding_tree=dst, pipeline_depth=depth)
+    for k in tree:
+        np.testing.assert_array_equal(np.asarray(back[k]), tree[k])
+    c = spans.snapshot()
+    target = sum(index_volume(cell) * 4 for g in dst.values()
+                 for cell in g.indices())
+    assert c["counters"]["ckpt.restore.bytes_placed"] == target
+    assert c["counters"]["ckpt.restore.bytes_read"] >= target
+    assert c["counters"]["ckpt.restore.runs"] > 0
+    assert c["spans"]["ckpt.restore.put"]["count"] == 2
