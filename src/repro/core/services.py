@@ -44,6 +44,7 @@ class KernelServices:
 
     def __init__(self, dev: BlockDevice, *, checksum: Callable[[bytes], int],
                  checksum_batch: Optional[Callable] = None,
+                 warm_checksum_batch: Optional[Callable[[int], None]] = None,
                  writeback: str = "delayed", cache_capacity: int = 4096,
                  binding: str = "kernel", checksum_impl: str = "crc32"):
         self._dev = dev
@@ -54,6 +55,7 @@ class KernelServices:
         self._sb_state = _SbState(dev, self._cache)
         self._checksum = checksum
         self._checksum_batch = checksum_batch
+        self._warm_checksum_batch = warm_checksum_batch
         self._log: List[str] = []
         # Batching observability: the fs_micro --batched acceptance check
         # reads these (one checksum_batch launch per flushed batch, bulk
@@ -156,6 +158,13 @@ class KernelServices:
             return self._checksum_batch(blocks)
         return [self._checksum(b) for b in blocks]
 
+    def warm_checksum_batch(self, max_blocks: int) -> None:
+        """Compile every shape a ``checksum_batch`` of up to ``max_blocks``
+        blocks launches, where the binding's hash is a compiled kernel:
+        the journal calls this when it is bound, so no commit compiles."""
+        if self._warm_checksum_batch is not None:
+            self._warm_checksum_batch(max_blocks)
+
     def time(self) -> float:
         return _time.time()
 
@@ -202,6 +211,8 @@ def kernel_binding(dev: BlockDevice, **kw) -> KernelServices:
         dev, checksum=functools.partial(bh_ops.checksum, interpret=interpret),
         checksum_batch=functools.partial(bh_ops.checksum_batch,
                                          interpret=interpret),
+        warm_checksum_batch=functools.partial(bh_ops.warm_batch,
+                                              interpret=interpret),
         binding="kernel",
         checksum_impl="blockhash-interpret" if interpret else
         "blockhash-pallas", **kw)

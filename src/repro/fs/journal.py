@@ -9,7 +9,10 @@ commit (several ops per transaction until fsync or the log fills).
 
 The per-block checksum in the commit record uses the kernel-services
 checksum (Pallas crc32c in the kernel binding) — torn journal writes are
-detected at recovery.
+detected at recovery. The record carries a crc32 of its own
+(``layout.pack_log_record``), so a header whose write tore is not replayed.
+Binding the journal compiles every batch shape a commit of its log can
+hash, so no commit compiles one.
 
 Chain transactions
 ------------------
@@ -67,17 +70,13 @@ serialization point:
 
 from __future__ import annotations
 
-import struct
 import threading
 from typing import Dict, List, Optional
 
 from repro.core.capability import SuperBlockCap
 from repro.core.interface import Errno, FsError
 from repro.core.spans import count, span, traced
-from repro.fs.layout import BSIZE, SuperBlock
-
-_HDR_FMT_HEAD = "<III"  # magic, n, seq
-_HDR_MAGIC = 0x4A524E4C  # "JRNL"
+from repro.fs.layout import SuperBlock, pack_log_record, unpack_log_record
 
 
 class JournalFull(FsError):
@@ -111,6 +110,7 @@ class Journal:
         self.sb_cap = sb_cap
         self.sb = sb
         self.capacity = sb.nlog - 1  # minus header block
+        services.warm_checksum_batch(self.capacity)
         self.batched_install = batched_install  # writepages-style install
         self._lock = threading.RLock()
         self._cv = threading.Condition(self._lock)  # chain-scope transitions
@@ -327,12 +327,10 @@ class Journal:
         # (batched: one Pallas kernel launch per transaction)
         with span("journal.commit.hash"):
             sums = self.ks.checksum_batch([data for _h, data in items])
-            hdr = struct.pack(_HDR_FMT_HEAD, _HDR_MAGIC, len(items),
-                              self._seq)
-            for (home, _data), cks in zip(items, sums):
-                hdr += struct.pack("<II", home, cks)
+            hdr = pack_log_record(self._seq, [
+                (home, cks) for (home, _data), cks in zip(items, sums)])
             with self.ks.sb_getblk_zero(self.sb_cap, self.sb.logstart) as bh:
-                bh.data()[: len(hdr)] = hdr
+                bh.data()[:] = hdr
                 self.ks.bwrite_sync(self.sb_cap, bh)
         # 3) install to home locations
         with span("journal.commit.install"):
@@ -361,15 +359,9 @@ class Journal:
         """Replay a committed transaction found in the journal. Returns the
         number of blocks installed (0 if log was clean or torn)."""
         with self.ks.sb_bread(self.sb_cap, self.sb.logstart) as bh:
-            raw = bytes(bh.data())
-        magic, n, _seq = struct.unpack_from(_HDR_FMT_HEAD, raw)
-        if magic != _HDR_MAGIC or n == 0 or n > self.capacity:
+            entries = unpack_log_record(bytes(bh.data()), self.sb)
+        if entries is None:
             return 0
-        entries = []
-        off = struct.calcsize(_HDR_FMT_HEAD)
-        for i in range(n):
-            home, cks = struct.unpack_from("<II", raw, off + 8 * i)
-            entries.append((home, cks))
         # verify checksums against journal data blocks (torn-write detection)
         datas = []
         raws = []
@@ -387,7 +379,7 @@ class Journal:
                 self.ks.bwrite_sync(self.sb_cap, bh)
         with self.ks.sb_getblk_zero(self.sb_cap, self.sb.logstart) as bh:
             self.ks.bwrite_sync(self.sb_cap, bh)
-        return n
+        return len(entries)
 
     # --- upgrade support (§4.8) --------------------------------------------------------
     def extract_state(self) -> Dict:

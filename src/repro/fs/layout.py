@@ -15,7 +15,8 @@ from __future__ import annotations
 
 import dataclasses
 import struct
-from typing import List
+import zlib
+from typing import List, Optional, Sequence, Tuple
 
 BSIZE = 4096
 FSMAGIC = 0x10203040
@@ -41,6 +42,22 @@ NAME_MAX = DIRENT_SIZE - 4 - 1  # u32 ino + NUL
 # delete marker must not be silently evicted by an unrelated create).
 WHITEOUT_INO = 0xFFFFFFFF  # u32 max — can never collide with a real ino
 
+# The journal's commit record (repro.fs.journal) is ONE block: a head
+# (magic, n, seq), a (home block, checksum) pair per logged block, and in
+# the block's last 4 bytes a crc32 of all before it, so a header whose
+# write tore is never read as a commit. One transaction therefore holds at
+# most LOG_MAX_ENTRIES blocks, and a log is at most that many data blocks
+# behind its header block.
+LOG_MAGIC = 0x4A524E32     # "JRN2"
+LOG_MAGIC_V1 = 0x4A524E4C  # "JRNL": the record before it carried a crc32
+LOG_HEAD_FMT = "<III"
+LOG_ENTRY_FMT = "<II"
+_LOG_CRC_OFF = BSIZE - 4
+LOG_MAX_ENTRIES = ((_LOG_CRC_OFF - struct.calcsize(LOG_HEAD_FMT))
+                   // struct.calcsize(LOG_ENTRY_FMT))  # 510
+NLOG_MAX = 1 + LOG_MAX_ENTRIES
+NLOG_MIN = 64
+
 
 @dataclasses.dataclass
 class SuperBlock:
@@ -65,6 +82,44 @@ class SuperBlock:
     def unpack(cls, raw: bytes) -> "SuperBlock":
         vals = struct.unpack_from(cls._FMT, raw)
         return cls(*vals)
+
+
+def pack_log_record(seq: int, entries: Sequence[Tuple[int, int]]) -> bytes:
+    """The commit record, one block, of a transaction whose logged blocks
+    have these (home block, checksum) ``entries``."""
+    assert 0 < len(entries) <= LOG_MAX_ENTRIES, len(entries)
+    raw = bytearray(BSIZE)
+    struct.pack_into(LOG_HEAD_FMT, raw, 0, LOG_MAGIC, len(entries), seq)
+    off, step = struct.calcsize(LOG_HEAD_FMT), struct.calcsize(LOG_ENTRY_FMT)
+    for i, (home, cks) in enumerate(entries):
+        struct.pack_into(LOG_ENTRY_FMT, raw, off + step * i, home, cks)
+    struct.pack_into("<I", raw, _LOG_CRC_OFF, zlib.crc32(raw[:_LOG_CRC_OFF]))
+    return bytes(raw)
+
+
+def unpack_log_record(raw: bytes, sb: SuperBlock
+                      ) -> Optional[List[Tuple[int, int]]]:
+    """The (home block, checksum) entries of the commit record in the
+    header block ``raw``, or None where it holds no whole one: a cleared
+    header, or one whose write tore. A record of the format before the
+    crc32 (LOG_MAGIC_V1) is still read, so a log written then replays."""
+    magic, n, _seq = struct.unpack_from(LOG_HEAD_FMT, raw)
+    if magic == LOG_MAGIC:
+        (crc,) = struct.unpack_from("<I", raw, _LOG_CRC_OFF)
+        if zlib.crc32(raw[:_LOG_CRC_OFF]) != crc:
+            return None
+    elif magic != LOG_MAGIC_V1:
+        return None
+    if not 0 < n <= sb.nlog - 1:
+        return None
+    off, step = struct.calcsize(LOG_HEAD_FMT), struct.calcsize(LOG_ENTRY_FMT)
+    entries = list(struct.iter_unpack(LOG_ENTRY_FMT,
+                                      raw[off: off + step * n]))
+    # the journal logs inode, bitmap and data blocks only: a home outside
+    # them is a header torn over zeros, never a commit to replay
+    if not all(sb.inodestart <= home < sb.size for home, _cks in entries):
+        return None
+    return entries
 
 
 @dataclasses.dataclass
@@ -98,7 +153,24 @@ def unpack_dirent(raw: bytes, off: int):
     return ino, name
 
 
-def geometry(n_blocks: int, ninodes: int = 4096, nlog: int = 64) -> SuperBlock:
+def log_blocks(n_blocks: int) -> int:
+    """The log mkfs gives a device of ``n_blocks``: a 256th of the device,
+    as mke2fs sizes an ext4 journal from its device, between NLOG_MIN
+    (every device up to 16,384 blocks) and NLOG_MAX."""
+    return min(NLOG_MAX, max(NLOG_MIN, n_blocks // 256))
+
+
+def geometry(n_blocks: int, ninodes: int = 4096,
+             nlog: Optional[int] = None) -> SuperBlock:
+    """The layout of a device of ``n_blocks``; ``nlog`` None sizes the
+    log from the device (``log_blocks``)."""
+    if nlog is None:
+        nlog = log_blocks(n_blocks)
+    if not 2 <= nlog <= NLOG_MAX:
+        raise ValueError(
+            f"nlog {nlog}: a log is its header block and 1 to "
+            f"{LOG_MAX_ENTRIES} data blocks, the most one commit record "
+            "can name")
     logstart = 1
     inodestart = logstart + nlog
     ninodeblocks = (ninodes + IPB - 1) // IPB

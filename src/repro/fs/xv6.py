@@ -77,8 +77,10 @@ class Xv6Options:
     dedup: bool = False  # content-addressed data plane (repro.fs.blockstore)
 
 
-def mkfs(services, ninodes: int = 4096, nlog: int = 64) -> None:
-    """Format the device: superblock, journal, inode table, bitmap, root."""
+def mkfs(services, ninodes: int = 4096, nlog: Optional[int] = None) -> None:
+    """Format the device: superblock, journal, inode table, bitmap, root.
+    The journal is sized from the device (``layout.log_blocks``) unless
+    ``nlog`` is given."""
     sb_cap = services.superblock()
     n = sb_cap.n_blocks
     geo = L.geometry(n, ninodes=ninodes, nlog=nlog)

@@ -82,6 +82,20 @@ def checksum(data, *, interpret: bool = False) -> int:
     return int(out)
 
 
+@functools.lru_cache(maxsize=None)
+def _warm_bucket(rows: int, block_rows: int, interpret: bool) -> None:
+    hash_rows(np.zeros((rows, K.WORDS), np.uint32), _pows(),
+              block_rows=block_rows, interpret=interpret).block_until_ready()
+
+
+@traced("blockhash.warm")
+def warm_batch(max_n: int, *, interpret: bool = False) -> None:
+    """Compile, once a process, each bucket a ``checksum_batch`` of 1 to
+    ``max_n`` blocks launches."""
+    for rows, block_rows in sorted({bucket(n) for n in range(1, max_n + 1)}):
+        _warm_bucket(rows, block_rows, interpret)
+
+
 def checksum_batch(blocks, *, interpret: bool = False) -> list:
     """Hash many buffers in one launch when each fits a row (the journal
     commit's blocks); longer buffers are hashed one by one."""

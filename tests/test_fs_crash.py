@@ -128,17 +128,15 @@ def _crash_recovery_body(ops, crash_after, data_seed):
 def test_torn_journal_commit_discarded():
     """Corrupt one journal data block after a staged commit record: recovery
     must detect the checksum mismatch and discard (no partial replay)."""
-    import struct
-    from repro.fs.journal import _HDR_MAGIC, _HDR_FMT_HEAD
+    from repro.fs.layout import pack_log_record
 
     dev, ks, fs, v = _fresh_fs()
     v.write_file("/a", b"A" * 4096)
     fs.journal.commit()
     geo = fs.geo
     bogus = b"\x42" * 4096
-    hdr = struct.pack(_HDR_FMT_HEAD, _HDR_MAGIC, 1, 99)
-    hdr += struct.pack("<II", geo.datastart + 5, ks.checksum(bogus))
-    dev.write_block(geo.logstart, hdr + b"\0" * (4096 - len(hdr)))
+    dev.write_block(geo.logstart, pack_log_record(
+        99, [(geo.datastart + 5, ks.checksum(bogus))]))
     dev.write_block(geo.logstart + 1, b"TORN" * 1024)  # checksum mismatch
     fs2 = Xv6FileSystem(Xv6Options())
     ks2 = kernel_binding(dev)

@@ -49,7 +49,9 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n", [1, 63, 64])
+# a commit of a 64-block log hashes up to 63 blocks, of the widest log
+# (layout.NLOG_MAX) up to 510, in the 512-row bucket
+@pytest.mark.parametrize("n", [1, 63, 64, 255, 510])
 def test_blockhash_batch_compiles(shape, n):
     rows, block_rows = bh_ops.bucket(n)
     compiled = bh_ops.hash_rows.lower(
